@@ -44,8 +44,10 @@ def main(smoke: bool = False) -> None:
     cfg = pgt_dcrnn.PGTDCRNNConfig(num_nodes=n, hidden=16, input_len=6, horizon=6)
     params = pgt_dcrnn.init(jax.random.PRNGKey(0), cfg)
 
-    def loss_fn(p, x, y):
+    def loss(sup, p, x, y):
         return pgt_dcrnn.loss_fn(p, cfg, sup, x, y), {}
+
+    loss_fn = jax.tree_util.Partial(loss, sup)
 
     span = spec.in_len + spec.horizon
     window_bytes = span * n * 2 * 4  # one (x,y) span in f32

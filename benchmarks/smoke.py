@@ -140,8 +140,10 @@ def _prefetch_bench(staleness: int) -> None:
                                    horizon=2)
     params = pgt_dcrnn.init(jax.random.PRNGKey(0), cfg)
 
-    def loss_fn(p, x, y):
+    def loss(sup, p, x, y):
         return pgt_dcrnn.loss_fn(p, cfg, sup, x, y), {}
+
+    loss_fn = jax.tree_util.Partial(loss, sup)
 
     mesh = make_host_mesh()
 

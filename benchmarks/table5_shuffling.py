@@ -38,8 +38,10 @@ def main() -> None:
     params0 = pgt_dcrnn.init(jax.random.PRNGKey(0), cfg)
     mesh = make_host_mesh()
 
-    def loss_fn(p, x, y):
+    def loss(sup, p, x, y):
         return pgt_dcrnn.loss_fn(p, cfg, sup, x, y), {}
+
+    loss_fn = jax.tree_util.Partial(loss, sup)
 
     for world in (2, 4):
         for name, placement in ARMS:

@@ -34,13 +34,15 @@ cfg = pgt_dcrnn.PGTDCRNNConfig(num_nodes=NODES, hidden=16,
 params = pgt_dcrnn.init(jax.random.PRNGKey(0), cfg)
 
 
-def loss_fn(p, x, y):
+def loss_fn(supports, p, x, y):
     return pgt_dcrnn.loss_fn(p, cfg, supports, x, y), {}
 
 
-# 3. the pipeline: placement + sampler + fused gather/step in one call
+# 3. the pipeline: placement + sampler + fused gather/step in one call; the
+#    supports ride into the step as arguments through the Partial
 pipe = build_pipeline(
-    series, WindowSpec(horizon=HORIZON), make_host_mesh(), loss_fn, params,
+    series, WindowSpec(horizon=HORIZON), make_host_mesh(),
+    jax.tree_util.Partial(loss_fn, supports), params,
     PipelineConfig(batch_per_rank=BATCH, adam=AdamConfig(lr=5e-3),
                    loop=TrainLoopConfig(epochs=3, log_every=10)))
 ds = pipe.dataset

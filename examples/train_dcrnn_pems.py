@@ -50,11 +50,12 @@ def main() -> None:
     supports = tuple(jnp.asarray(s) for s in transition_matrices(adj))
     series = make_traffic_series(args.entries, args.nodes, adjacency=adj)
 
-    def loss_fn(p, x, y):
+    def loss_fn(supports, p, x, y):
         return dcrnn.loss_fn(p, cfg, supports, x, y), {}
 
     pipe = build_pipeline(
-        series, WindowSpec(horizon=12), make_host_mesh(), loss_fn, params,
+        series, WindowSpec(horizon=12), make_host_mesh(),
+        jax.tree_util.Partial(loss_fn, supports), params,
         PipelineConfig(
             batch_per_rank=args.batch, gather=args.gather,
             adam=AdamConfig(lr=1e-2),

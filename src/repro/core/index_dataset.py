@@ -11,7 +11,6 @@ import dataclasses
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import windows as W
@@ -50,11 +49,13 @@ class IndexDataset:
 
     # -------------------------------------------------------------- placement
     def to_device(self, sharding=None) -> "IndexDataset":
-        """GPU-index-batching: one host→device transfer of the compact series."""
-        arr = jnp.asarray(self.series)
-        if sharding is not None:
-            arr = jax.device_put(arr, sharding)
-        return dataclasses.replace(self, series=arr)
+        """GPU-index-batching: one host→device transfer of the compact series.
+
+        The series goes from host memory straight to its sharding: each
+        device receives only its own shard, never the whole series first.
+        """
+        return dataclasses.replace(self,
+                                   series=jax.device_put(self.series, sharding))
 
     # ------------------------------------------------------------- accounting
     @property

@@ -51,6 +51,7 @@ import os
 import tempfile
 import threading
 import time
+import warnings
 from typing import Any, Callable
 
 import jax
@@ -377,10 +378,13 @@ def _tune_body(spec: OpSpec, backend: str, dims: dict, static: dict, dtype,
                 t = _timed(lambda: fn(*sargs), warmup=policy.warmup,
                            iters=policy.iters)
             except Exception as e:  # noqa: BLE001 — a candidate that
-                # cannot lower on this backend is disqualified, not fatal
-                candidates[label] = {
-                    "us": None,
-                    "rejected": f"{type(e).__name__}: {e}"[:200]}
+                # cannot lower on this backend is disqualified, not fatal,
+                # but the refusal is reported, not only recorded
+                reason = f"{type(e).__name__}: {e}"
+                warnings.warn(f"autotune {spec.name}|{backend}: candidate "
+                              f"{label} refused: {reason[:500]}",
+                              RuntimeWarning, stacklevel=2)
+                candidates[label] = {"us": None, "rejected": reason[:200]}
                 continue
             us = 1e6 * t
             candidates[label] = {"us": round(us, 2)}
@@ -469,9 +473,9 @@ def dispatch(op: str, *args, **static):
     Resolution happens per call: backend read NOW, bucket computed from the
     call shapes, verdict looked up (memoized per bucket — keyed by backend,
     so nothing a racing thread primes can pin a foreign backend's verdict).
-    A stale cache entry naming a variant that no longer exists, or whose
-    params no longer lower, falls back to the static default instead of
-    crashing the train step.
+    A stale cache entry naming a variant that no longer exists falls back to
+    the static default.  A lowering that fails raises: swapping in another
+    one would hide a kernel the compiler refuses.
     """
     spec = _OPS[op]
     backend = resolve_backend(None)
@@ -483,13 +487,7 @@ def dispatch(op: str, *args, **static):
     if var is None:  # cache from an older registry revision
         name, params = spec.default(backend, dims)
         var, verdict = by_name[name], Verdict(name, params, source="default")
-    try:
-        return _built(spec, var, static, verdict.params)(*args)
-    except Exception:
-        name, params = spec.default(backend, dims)
-        if name == verdict.variant and params == verdict.params:
-            raise  # the default itself failed: a real error, surface it
-        return _built(spec, by_name[name], static, params)(*args)
+    return _built(spec, var, static, verdict.params)(*args)
 
 
 # ------------------------------------------------------------- op specs
@@ -511,16 +509,6 @@ def _synth_series(t: int, c: int, dtype) -> np.ndarray:
 def _ref_default(backend: str, dims: dict) -> tuple[str, dict]:
     del dims
     return ("ref", {})
-
-
-def _bc_grid(dims: dict, kd: KernelDefaults) -> tuple:
-    """block_c candidates for the Pallas gather: the ops-level heuristic
-    (None) plus lane-multiples that do not dwarf the bucket's row width."""
-    out: list[dict] = [{"block_c": None}]
-    for b in block_candidates(kd.lane, lo=kd.lane):
-        if b <= 2 * dims.get("c", b):
-            out.append({"block_c": b})
-    return tuple(out)
 
 
 # window_gather: series [T, ...], starts [B] -> [B, span, ...]
@@ -562,14 +550,13 @@ def _wg_variants() -> tuple[Variant, ...]:
 
     def pallas(static, params):
         from repro.kernels.window_gather.ops import window_gather
-        span, bc = static["span"], params.get("block_c")
+        span = static["span"]
         return jax.jit(lambda s, st: window_gather(s, st, span=span,
-                                                   use_pallas=True,
-                                                   block_c=bc))
+                                                   use_pallas=True))
 
     return (Variant("ref", ref),
             Variant("take", take),
-            Variant("pallas", pallas, grid=_bc_grid))
+            Variant("pallas", pallas))
 
 
 def _pallas_or_ref(params_for_pallas: Callable[[KernelDefaults], dict]):
@@ -591,7 +578,7 @@ register_op(OpSpec(
     describe=_wg_describe,
     variants=_wg_variants,
     synth=_wg_synth,
-    default=_pallas_or_ref(lambda kd: {"block_c": None}),
+    default=_pallas_or_ref(lambda kd: {}),
 ))
 
 
@@ -637,12 +624,10 @@ def _xy_variants() -> tuple[Variant, ...]:
 
     def pallas(static, params):
         from repro.kernels.window_gather.ops import window_gather
-        il, hz, bc = static["input_len"], static["horizon"], \
-            params.get("block_c")
+        il, hz = static["input_len"], static["horizon"]
 
         def fn(series, starts):
-            w = window_gather(series, starts, span=il + hz, use_pallas=True,
-                              block_c=bc)
+            w = window_gather(series, starts, span=il + hz, use_pallas=True)
             return w[:, :il], w[:, il:]
 
         return jax.jit(fn)
@@ -650,14 +635,14 @@ def _xy_variants() -> tuple[Variant, ...]:
     return (Variant("slice", slice_),
             Variant("take", take),
             Variant("fused", fused),
-            Variant("pallas", pallas, grid=_bc_grid))
+            Variant("pallas", pallas))
 
 
 def _xy_default(backend: str, dims: dict) -> tuple[str, dict]:
     kd = kernel_defaults(backend)
     if kd.interpret:
         return ("slice", {})  # the dense lowering the CPU bench crowns
-    return ("pallas", {"block_c": None})
+    return ("pallas", {})
 
 
 register_op(OpSpec(

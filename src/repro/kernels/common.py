@@ -29,12 +29,10 @@ class KernelDefaults:
 
     ``lane``        last-dim tile quantum (TPU lane width); last-dim blocks
                     should be multiples of this.
-    ``block_c_max`` widest last-dim the window gather keeps as ONE block
-                    when it is lane-aligned.
-    ``block_c_cap`` last-dim block cap when the width is ragged.
     ``block_q/k``   flash-attention query/key tile lengths.
     ``block_n``     diffusion-conv node tile.
-    ``block_b``     linear-scan batch tile (used when the batch divides it).
+    ``block_b``     linear-scan and diffusion-conv batch tile (used when the
+                    batch divides it).
     ``scan_chunk``  linear-scan sequence chunk.
     ``interpret``   run Pallas in interpret mode (CPU has no Mosaic/Triton
                     lowering; interpret executes the kernel body in Python
@@ -42,8 +40,6 @@ class KernelDefaults:
     """
 
     lane: int = 128
-    block_c_max: int = 4096
-    block_c_cap: int = 2048
     block_q: int = 256
     block_k: int = 256
     block_n: int = 128

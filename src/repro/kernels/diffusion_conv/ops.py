@@ -6,6 +6,8 @@ stays False and the same call sites get the real kernel.
 """
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,10 +58,11 @@ def diffusion_conv(
     bsz, n, c = x.shape
     h = w.shape[1]
     n_pad = int(np.ceil(n / block_n) * block_n)
+    block_b = math.gcd(bsz, kd.block_b)
 
-    z0 = _pad_nodes(jnp.transpose(x, (1, 0, 2)), n_pad, (0,))  # [Np, B, C]
+    z0 = _pad_nodes(x, n_pad, (1,))  # [B, Np, C]
     # Identity-hop projection (plain matmul — XLA handles it optimally).
-    y = jnp.einsum("nbc,ch->nbh", z0, w[:c].astype(x.dtype))
+    y = jnp.einsum("bnc,ch->bnh", z0, w[:c].astype(x.dtype))
     wk = w[c:].reshape(len(supports), k_hops, c, h)
 
     for si, s in enumerate(supports):
@@ -68,6 +71,6 @@ def diffusion_conv(
         for k in range(k_hops):
             z, y = hop_project(
                 s_p, z, wk[si, k].astype(x.dtype), y,
-                block_n=block_n, interpret=kd.interpret,
+                block_n=block_n, block_b=block_b, interpret=kd.interpret,
             )
-    return jnp.transpose(y[:n], (1, 0, 2)) + b
+    return y[:, :n] + b

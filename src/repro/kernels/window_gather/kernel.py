@@ -6,14 +6,16 @@ step's BlockSpec index_map reads ``starts[b]`` to aim the HBM→VMEM DMA at the
 right time-rows of the resident series.  No materialised snapshot array ever
 exists in HBM — the paper's eq.-2 memory model holds on device.
 
-Grid: (B, span, C/bc)
-  series block (1, bc)  <- series[starts[b] + t, c-block]   (DMA, no compute)
-  out    block (1,1,bc) -> out[b, t, c-block]
+The series is viewed as ``[T, 1, C]``: the unit axis makes the block's two
+minor dims ``(1, C)`` equal the array's, which Mosaic accepts for any ``C``,
+and leaves time as an untiled major axis, so a window may start at any row.
 
-The kernel body is a pure VMEM copy; the win is that the index indirection is
-resolved by the scalar-prefetch unit concurrently with the previous block's
-DMA, so gathers pipeline at full HBM bandwidth instead of issuing B separate
-host-driven slices.
+Grid: (B,)
+  series block (span, 1, C)  <- series[starts[b] : starts[b] + span]  (one DMA)
+  out    block (span, 1, C)  -> out[b]
+
+The kernel body is a pure VMEM copy; the index indirection is resolved by
+the scalar-prefetch unit while the previous window's DMA is in flight.
 """
 from __future__ import annotations
 
@@ -26,43 +28,41 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_kernel(starts_ref, series_ref, out_ref):
-    # starts_ref lives in SMEM (scalar prefetch); blocks are pre-aimed by the
-    # index_map below, so the body is a straight VMEM copy.
+    # starts_ref lives in SMEM (scalar prefetch); the input block is aimed by
+    # the index_map below, so the body is a straight VMEM copy.
     del starts_ref
-    out_ref[0] = series_ref[...]
+    out_ref[...] = series_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("span", "block_c", "interpret"))
+@functools.partial(jax.jit, static_argnames=("span", "interpret"))
 def window_gather(
     series: jnp.ndarray,
     starts: jnp.ndarray,
     *,
     span: int,
-    block_c: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """series: [T, C], starts: [B] int32 -> [B, span, C].
 
-    C must be a multiple of ``block_c`` (ops.py pads).  ``span`` is
-    input_len + horizon — x/y are sliced from the result by the caller.
+    ``span`` is input_len + horizon — x/y are sliced from the result by the
+    caller.
     """
     t, c = series.shape
     b = starts.shape[0]
-    bc = block_c or c
-    assert c % bc == 0, (c, bc)
-
-    grid = (b, span, c // bc)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(b,),
             in_specs=[
-                # time index comes from the prefetched starts array
-                pl.BlockSpec((1, bc), lambda i, j, k, starts: (starts[i] + j, k)),
+                # element offsets: the window starts at row starts[i]
+                pl.BlockSpec((pl.Element(span), pl.Element(1), pl.Element(c)),
+                             lambda i, starts: (starts[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, bc), lambda i, j, k, starts: (i, j, k)),
+            out_specs=pl.BlockSpec((None, span, 1, c),
+                                   lambda i, starts: (i, 0, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, span, c), series.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, span, 1, c), series.dtype),
         interpret=interpret,
-    )(starts, series)
+    )(starts, series.reshape(t, 1, c))
+    return out.reshape(b, span, c)

@@ -1,8 +1,8 @@
 """Public window-gather op: jnp oracle by default, Pallas kernel on request.
 
-Handles arbitrary trailing shape by flattening to [T, C], padding C to the
-block size, and restoring the shape afterwards.  The batching layer
-(`repro.core.batching`) routes through here when ``use_pallas=True``.
+Handles arbitrary trailing shape by flattening to [T, C] and restoring the
+shape afterwards.  The batching layer (`repro.core.batching`) routes through
+here when ``use_pallas=True``.
 """
 from __future__ import annotations
 
@@ -20,14 +20,13 @@ def window_gather(
     *,
     span: int,
     use_pallas: bool = False,
-    block_c: int | None = None,
     backend: str | None = None,
     impl: str | None = None,
 ) -> jnp.ndarray:
     """series: [T, ...], starts: [B] -> [B, span, ...].
 
-    Tiling/interpret defaults resolve per call from ``backend`` (None = the
-    ambient ``jax.default_backend()``, read now — never cached).  ``impl``
+    Interpret mode resolves per call from ``backend`` (None = the ambient
+    ``jax.default_backend()``, read now — never cached).  ``impl``
     overrides ``use_pallas``: ``"ref"`` / ``"pallas"`` force a lowering,
     ``"auto"`` routes through the measured shape-bucketed dispatcher
     (:mod:`repro.kernels.autotune`), which picks the fastest VERIFIED
@@ -43,20 +42,12 @@ def window_gather(
     if not use_pallas:
         return window_gather_ref(series, starts, span=span)
 
-    kd = kernel_defaults(backend)
     t = series.shape[0]
     trailing = series.shape[1:]
     c = int(np.prod(trailing)) if trailing else 1
-    flat = series.reshape(t, c)
-    if block_c is None:
-        block_c = (c if c % kd.lane == 0 and c <= kd.block_c_max
-                   else min(c, kd.block_c_cap))
-    pad = (-c) % block_c
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
-    out = _window_gather_kernel(flat, starts.astype(jnp.int32), span=span,
-                                block_c=block_c, interpret=kd.interpret)
-    out = out[..., :c]
+    out = _window_gather_kernel(series.reshape(t, c), starts.astype(jnp.int32),
+                                span=span,
+                                interpret=kernel_defaults(backend).interpret)
     return out.reshape((starts.shape[0], span) + trailing)
 
 

@@ -72,6 +72,30 @@ def collective_bytes(hlo_text: str) -> dict[str, int]:
     return out
 
 
+_CONST_RE = re.compile(r"stablehlo\.constant .*: tensor<([^>]*)>")
+_MLIR_BITS = {"f64": 64, "f32": 32, "f16": 16, "bf16": 16, "i64": 64,
+              "i32": 32, "i16": 16, "i8": 8, "i1": 8, "ui32": 32, "ui8": 8}
+
+
+def largest_constant_bytes(lowered) -> int:
+    """Bytes of the largest constant embedded in a lowered program.
+
+    A concrete array closed over by a jitted function becomes such a
+    constant: a second device copy, and a program that grows with it.
+    Large constants are printed elided, so this never renders their data.
+    """
+    text = lowered.compiler_ir("stablehlo").operation.get_asm(
+        large_elements_limit=16)
+    largest = 0
+    for ty in _CONST_RE.findall(text):
+        *dims, dt = ty.split("x")
+        n = 1
+        for d in dims:
+            n *= int(d)
+        largest = max(largest, n * _MLIR_BITS.get(dt, 32) // 8)
+    return largest
+
+
 def partitioned_halo_evidence(mesh=None, *, entries: int = 256, nodes: int = 4,
                               features: int = 2, global_batch: int = 16,
                               input_len: int = 3, horizon: int = 3) -> dict:
@@ -89,7 +113,6 @@ def partitioned_halo_evidence(mesh=None, *, entries: int = 256, nodes: int = 4,
     ``data_bytes`` = everything except the gradient all-reduce.
     """
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.batching import gather_batch_fused
@@ -125,9 +148,9 @@ def partitioned_halo_evidence(mesh=None, *, entries: int = 256, nodes: int = 4,
         l, g = jax.value_and_grad(loss)(w, series_shard, starts_shard - lo)
         return jax.lax.pmean(l, all_axes), jax.lax.pmean(g, all_axes)
 
-    step_local = shard_map(body, mesh=mesh,
-                           in_specs=(P(), P(dp), P(dp)),
-                           out_specs=(P(), P()), check_rep=False)
+    step_local = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(), P(dp), P(dp)),
+                               out_specs=(P(), P()), check_vma=False)
 
     sds = jax.ShapeDtypeStruct
     args = (sds((features,), jnp.float32),

@@ -11,15 +11,13 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int | None = None) -> Mesh:
@@ -27,8 +25,8 @@ def make_host_mesh(*, model: int | None = None) -> Mesh:
     n = len(jax.devices())
     model = model or 1
     assert n % model == 0, (n, model)
-    return make_mesh((n // model, model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def shrink_mesh(mesh: Mesh, new_dp: int) -> Mesh:

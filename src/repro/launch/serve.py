@@ -17,7 +17,9 @@ clean backpressure instead of OOM-ing when the pool is exhausted.
 
 - ``engine`` (default) — everything in one process, as before;
 - ``fleet``  — coordinator: spawns ``--planes`` per-host worker processes
-  (re-invoking this module with ``--role worker``), assigns requests over
+  (re-invoking this module with ``--role worker``; on a TPU host each
+  worker is given a chip of its own, and more planes than chips is
+  refused), assigns requests over
   file mailboxes, tracks liveness via heartbeats, and re-prefills a dead
   worker's in-flight requests on survivors;
 - ``worker`` — one serving host: a single-plane engine pumping the file
@@ -41,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import model as lm
 from repro.serve import (Backpressure, FileMailbox, FleetEngine, ServeConfig,
                          ServeEngine, ServeWorker)
@@ -144,6 +147,29 @@ def _run_worker(args: argparse.Namespace) -> None:
 
 
 # -------------------------------------------------------------- coordinator
+def _probe_backend() -> tuple[str, int]:
+    """``(backend, local device count)`` as a worker would see them, asked
+    of a short-lived child process: the coordinator itself never touches
+    JAX, so it holds no chip, and the child releases the chip on exit."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.default_backend(), jax.local_device_count())"],
+        capture_output=True, text=True, check=True, timeout=300)
+    backend, count = out.stdout.split()[-2:]
+    return backend, int(count)
+
+
+def _worker_env(backend: str, wid: int) -> dict | None:
+    """The environment of worker ``wid``: on a TPU host each worker process
+    sees only chip ``wid`` (a chip belongs to one process at a time)."""
+    if backend != "tpu":
+        return None
+    return {**os.environ, "TPU_VISIBLE_CHIPS": str(wid),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + wid)}
+
+
 def _run_fleet(args: argparse.Namespace) -> None:
     """Coordinator: spawn per-host workers, drive the fleet, shut it down."""
     from repro.distributed.transport import FileHeartbeatTransport
@@ -151,6 +177,10 @@ def _run_fleet(args: argparse.Namespace) -> None:
     arch = get_arch(args.arch)
     if arch.lm is None:
         raise SystemExit(f"{args.arch} is not an LM arch")
+    backend, chips = _probe_backend()
+    if backend == "tpu" and args.planes > chips:
+        raise SystemExit(f"--planes {args.planes} needs one chip per worker; "
+                         f"this host has {chips} TPU chips")
     cfg = arch.smoke_config()
     fleet_dir = args.fleet_dir or tempfile.mkdtemp(prefix="serve-fleet-")
     hb = FileHeartbeatTransport(os.path.join(fleet_dir, "hb"))
@@ -176,8 +206,9 @@ def _run_fleet(args: argparse.Namespace) -> None:
                 "--block-size", str(args.block_size),
                 "--pool-blocks", str(args.pool_blocks),
                 "--seed", str(args.seed)]
-        procs.append(subprocess.Popen(argv))
-    print(f"# fleet: {args.planes} workers, mailboxes under {fleet_dir}")
+        procs.append(subprocess.Popen(argv, env=_worker_env(backend, wid)))
+    print(f"# fleet: {args.planes} {backend} workers, mailboxes under "
+          f"{fleet_dir}")
 
     prompts = _prompts(args, cfg.vocab)
     t0 = time.perf_counter()
@@ -252,6 +283,7 @@ def main() -> None:
                          "declared dead and its work re-prefilled")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.role == "worker":
         if args.fleet_dir is None:
             raise SystemExit("--role worker requires --fleet-dir")

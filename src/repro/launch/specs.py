@@ -355,7 +355,6 @@ def _stgnn_partitioned_step(mod, mcfg, adam, mesh: Mesh, in_len, hor, use_pallas
     each rank gathers from its own series shard, computes grads, and the only
     collective is the explicit gradient psum over the data axes.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     dp = dp_axes(mesh)
@@ -383,12 +382,12 @@ def _stgnn_partitioned_step(mod, mcfg, adam, mesh: Mesh, in_len, hor, use_pallas
         return {"params": new_p, "opt": new_opt}, l
 
     def step(state, series, starts, supports):
-        sm = shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, state), series_spec,
                       batch_spec, (rep, rep)),
             out_specs=(jax.tree.map(lambda _: rep, state), rep),
-            check_rep=False,
+            check_vma=False,
         )
         return sm(state, series, starts, supports)
 

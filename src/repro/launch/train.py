@@ -54,6 +54,7 @@ import argparse
 import dataclasses
 import json
 import time
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,7 @@ from repro.data import (gaussian_adjacency, make_token_stream, make_traffic_seri
 from repro.distributed import (LeaderHistorySink, LeaderTracker, latest_step,
                                make_transport)
 from repro.distributed.transport import tcp_addresses
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import dcrnn, pgt_dcrnn
 from repro.models.lm import model as lm
@@ -74,9 +76,10 @@ from repro.pipeline import ElasticConfig, PipelineConfig, build_pipeline
 from repro.train.loop import RestartSignal, TrainLoopConfig
 
 
-def _train_stgnn(arch, args, adam, sched, loop: TrainLoopConfig,
-                 sink: list | None = None):
-    """Full pipeline path: placement-aware sampler/sharding/fused step."""
+def stgnn_problem(arch, args):
+    """The host-side problem of an ST-GNN arch at the launcher's sizes:
+    ``(model config, series [entries, nodes, features], supports)``, all
+    generated from ``--seed``."""
     mcfg = arch.model
     if args.nodes:
         mcfg = dataclasses.replace(mcfg, num_nodes=args.nodes)
@@ -85,12 +88,21 @@ def _train_stgnn(arch, args, adam, sched, loop: TrainLoopConfig,
     supports = tuple(jnp.asarray(s) for s in transition_matrices(adj))
     series = make_traffic_series(args.entries, mcfg.num_nodes,
                                  mcfg.in_features, seed=args.seed, adjacency=adj)
+    return mcfg, series, supports
+
+
+def build_stgnn(arch, args, problem=None):
+    """The ST-GNN engine the launcher trains: placement-aware sampler,
+    series sharding and fused step over ``problem`` (default: built from
+    ``args`` by :func:`stgnn_problem`)."""
+    adam, sched, loop = train_config(args)
+    mcfg, series, supports = problem or stgnn_problem(arch, args)
     spec = WindowSpec(horizon=mcfg.horizon, input_len=mcfg.input_len)
 
     mod = dcrnn if isinstance(mcfg, dcrnn.DCRNNConfig) else pgt_dcrnn
     params = mod.init(jax.random.PRNGKey(args.seed), mcfg)
 
-    def loss_fn(p, x, y):
+    def loss_fn(supports, p, x, y):
         return mod.loss_fn(p, mcfg, supports, x, y), {}
 
     mesh = make_host_mesh()
@@ -100,14 +112,20 @@ def _train_stgnn(arch, args, adam, sched, loop: TrainLoopConfig,
     if args.batch % dp:
         raise SystemExit(f"--batch {args.batch} not divisible by "
                          f"data-parallel size {dp}")
-    pipe = build_pipeline(
-        series, spec, mesh, loss_fn, params,
+    return build_pipeline(
+        series, spec, mesh, jax.tree_util.Partial(loss_fn, supports), params,
         PipelineConfig(batch_per_rank=args.batch // dp,
                        placement=Placement(args.placement),
                        gather=args.gather, halo=not args.no_halo,
                        seed=args.seed, adam=adam,
                        schedule=sched, loop=loop),
         elastic=_elastic_config(args))
+
+
+def _train_stgnn(arch, args, sink: list | None = None):
+    """Full pipeline path: placement-aware sampler/sharding/fused step."""
+    pipe = build_stgnn(arch, args)
+    loop = pipe.config.loop
     if args.resume and loop.ckpt_dir:
         step = latest_step(loop.ckpt_dir)
         if step is not None:
@@ -120,10 +138,10 @@ def _train_stgnn(arch, args, adam, sched, loop: TrainLoopConfig,
             transport.close()
 
 
-def _train_lm(arch, args, adam, sched, loop: TrainLoopConfig,
-              sink: list | None = None):
+def _train_lm(arch, args, sink: list | None = None):
     """Token-stream windows (nodes==1 case) through the same pipeline: the
     ``lm`` gather entry reconstructs (tokens, shifted labels) on-device."""
+    adam, sched, loop = train_config(args)
     cfg = arch.smoke_config() if args.smoke else arch.lm
     stream = np.asarray(make_token_stream(args.entries, cfg.vocab, seed=args.seed))
     spec = WindowSpec(horizon=1, input_len=args.seq_len)
@@ -278,7 +296,23 @@ def _write_plan(args, sig) -> None:
     print(f"re-mesh requested (exit {EX_REMESH}): {payload}")
 
 
-def main() -> None:
+def train_config(args) -> tuple[AdamConfig, Callable, TrainLoopConfig]:
+    """``(adam, lr schedule, loop config)`` from the launcher's flags."""
+    adam = AdamConfig(lr=args.lr)
+    total = max(args.steps, 100)
+    sched = lambda s: warmup_cosine(s, base_lr=args.lr, warmup_steps=total // 10,
+                                    total_steps=total)
+    loop = TrainLoopConfig(epochs=args.epochs, log_every=args.log_every,
+                           ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
+                           eval_every=args.eval_every,
+                           prefetch_depth=args.prefetch_depth,
+                           staleness=args.staleness,
+                           prefetch_chunk=args.prefetch_chunk,
+                           max_steps=args.steps)
+    return adam, sched, loop
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--entries", type=int, default=2_000)
@@ -286,7 +320,10 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=128, help="LM window")
     ap.add_argument("--batch", type=int, default=32, help="global batch")
     ap.add_argument("--epochs", type=int, default=1)
-    ap.add_argument("--steps", type=int, default=0, help="cap steps (0 = epochs)")
+    ap.add_argument("--steps", type=int, default=0,
+                    help="stop after this many steps (0 = run --epochs)")
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="log the step metrics every N steps")
     ap.add_argument("--lr", type=float, default=1e-2)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--smoke", action="store_true", help="reduced LM config")
@@ -382,7 +419,12 @@ def main() -> None:
                          "nothing; duplicate (epoch, step) rows from a "
                          "relaunch re-running an epoch tail are suppressed "
                          "(idempotent resume).  Process 0 writes it")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main() -> None:
+    args = parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
     # Set the autotune policy before anything builds a pipeline: 'auto'
     # dispatch resolves per call, so this only configures WHERE verdicts come
     # from — it never touches the backend (jax.distributed.initialize() below
@@ -418,16 +460,6 @@ def main() -> None:
               f"{jax.process_count()} (per-rank feed selection active)")
 
     arch = get_arch(args.arch)
-    adam = AdamConfig(lr=args.lr)
-    total = max(args.steps, 100)
-    sched = lambda s: warmup_cosine(s, base_lr=args.lr, warmup_steps=total // 10,
-                                    total_steps=total)
-    loop = TrainLoopConfig(epochs=args.epochs, log_every=10,
-                           ckpt_every=args.ckpt_every, ckpt_dir=args.ckpt_dir,
-                           eval_every=args.eval_every,
-                           prefetch_depth=args.prefetch_depth,
-                           staleness=args.staleness,
-                           prefetch_chunk=args.prefetch_chunk)
 
     t0 = time.perf_counter()
     # The sink mirrors every logged row AS IT LANDS, so the rows survive the
@@ -448,9 +480,9 @@ def main() -> None:
          if args.history_out else [])
     try:
         if arch.family == "stgnn":
-            state, history = _train_stgnn(arch, args, adam, sched, loop, sink)
+            state, history = _train_stgnn(arch, args, sink)
         else:
-            state, history = _train_lm(arch, args, adam, sched, loop, sink)
+            state, history = _train_lm(arch, args, sink)
     except RestartSignal as sig:
         # relaunch-mode elastic: the state is already checkpointed with its
         # (epoch, done_in_epoch) coordinates; hand the plan to the launcher.

@@ -30,6 +30,7 @@ ONDEMAND        ``P(data axes)`` on time    GlobalShuffleSampler (global
 from __future__ import annotations
 
 import dataclasses
+import warnings
 from typing import Any, Callable
 
 import jax
@@ -88,14 +89,19 @@ def _make_sampler(config: PipelineConfig, ds: IndexDataset, world: int):
                     ds.entries, ds.spec, ds.train_windows,
                     config.batch_per_rank, world, seed=config.seed,
                     halo=config.halo)
-            except ValueError:
+            except ValueError as e:
                 # A rank's shard holds no (or too few) train windows — e.g.
                 # the 70/10/20 split leaves the val/test-tail ranks empty,
                 # or stride > 1.  Fall back to the contiguous count-split,
                 # whose boundaries only approximate the device shards (some
                 # gathers cross shards) — widen the train fraction if strict
                 # locality matters.
-                pass
+                warnings.warn(
+                    f"PARTITIONED: no shard-aligned sampler over {world} "
+                    f"time shards ({e}); falling back to the count-split "
+                    f"LocalBatchShuffleSampler, whose gathers may cross "
+                    f"shards (global-index lowering)", RuntimeWarning,
+                    stacklevel=2)
         elif config.partition != "count":
             raise ValueError(f"unknown partition {config.partition!r}; "
                              "expected 'aligned' or 'count'")

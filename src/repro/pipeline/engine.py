@@ -133,8 +133,8 @@ class Engine:
     dataplane: DataPlane
     loss_fn: Callable
     init_params: Any
-    train_step: Callable
-    _eval_loss: Callable  # jitted (params, starts) -> (loss, metrics)
+    train_step: BoundStep  # (state, starts) -> (state, metrics)
+    _eval_loss: BoundStep  # (params, starts) -> (loss, metrics)
     elastic: ElasticConfig | None = None
     # One record per elastic restart: the plan plus the resume coordinates.
     restarts: list = dataclasses.field(default_factory=list)
@@ -205,7 +205,8 @@ class Engine:
         return self.dataplane.global_batch
 
     def describe(self) -> dict:
-        return self.dataplane.describe()
+        return {**self.dataplane.describe(),
+                "gather_lowering": gather_lowering(self.dataplane, self.config)}
 
     def batch_of_starts(self, window_ids: np.ndarray, *,
                         replicate: bool = False) -> jnp.ndarray:
@@ -646,9 +647,6 @@ def _shard_local_gather_ok(dataplane: DataPlane, config: PipelineConfig) -> bool
 def _shard_local_gather(gather: Callable, dataplane: DataPlane) -> Callable:
     """Wrap ``gather`` in a shard_map: each rank gathers from ITS series
     shard with shard-local offsets (global start − shard origin)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     mesh = dataplane.mesh
     axis = data_axes(mesh)[0]
     shard_len = dataplane.dataset.entries // int(mesh.shape[axis])
@@ -661,42 +659,81 @@ def _shard_local_gather(gather: Callable, dataplane: DataPlane) -> Callable:
     def fn(series, starts, *, input_len, horizon):
         import functools
         body = functools.partial(local, input_len=input_len, horizon=horizon)
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(axis), P(axis)),
-                         out_specs=(P(axis), P(axis)),
-                         check_rep=False)(series, starts)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(axis), P(axis)),
+                             out_specs=(P(axis), P(axis)),
+                             check_vma=False)(series, starts)
 
     return fn
 
 
+@dataclasses.dataclass(frozen=True)
+class BoundStep:
+    """A jitted ``fn(first, batch, *args)`` with ``args`` bound.
+
+    The bound arrays (the resident series, the loss's constants) enter the
+    program as jit ARGUMENTS: a closed-over concrete array would be embedded
+    in the program as a literal — a second device copy of the series and a
+    compile that grows with its size.  ``lower`` exposes the program the
+    call runs, for inspection.
+    """
+
+    fn: Callable
+    args: tuple
+
+    def __call__(self, first, batch):
+        return self.fn(first, batch, *self.args)
+
+    def lower(self, first, batch):
+        return self.fn.lower(first, batch, *self.args)
+
+
+def _as_partial(loss_fn: Callable) -> jax.tree_util.Partial:
+    """``loss_fn`` as a pytree: a ``jax.tree_util.Partial`` keeps its bound
+    arrays (e.g. a graph's supports) as leaves, so passing it to jit makes
+    them arguments of the step; a plain function becomes a leafless one."""
+    if isinstance(loss_fn, jax.tree_util.Partial):
+        return loss_fn
+    return jax.tree_util.Partial(loss_fn)
+
+
+def gather_lowering(dataplane: DataPlane, config: PipelineConfig) -> str:
+    """Which train-step gather lowering the engine builds for this plane:
+    ``"shard_local"`` (shard_map, no data collectives) or ``"global_index"``."""
+    return ("shard_local" if _shard_local_gather_ok(dataplane, config)
+            else "global_index")
+
+
 def _compile(dataplane: DataPlane, loss_fn: Callable, config: PipelineConfig):
     """(train_step, eval_loss) with the window gather fused over THIS data
-    plane's resident series — rebuilt on every re-mesh."""
+    plane's resident series — rebuilt on every re-mesh.
+
+    The series and ``loss_fn`` (with any arrays a ``Partial`` binds) are
+    arguments of both jitted programs, bound by :class:`BoundStep`; their
+    shardings come from the placed arrays (the loss's are replicated)."""
     gather = resolve_gather(config.gather)
     spec = dataplane.spec
-    series = dataplane.dataset.series
-    # The series is CLOSED OVER, so without a constraint GSPMD is free to
-    # re-shard the captured constant — it replicates it, silently voiding
-    # the PARTITIONED/ONDEMAND memory contract and hiding the gathers'
-    # cross-shard traffic.  Pin the placement's sharding inside the step.
-    pin = (dataplane.series_sharding if dataplane.mesh.size > 1 else None)
     # halo=False + aligned feeds: provably-local gathers lower as a
     # shard_map — zero data collectives.  Eval stays on the global-index
     # gather: val/test pools are drawn globally, not shard-aligned.
     train_gather = (_shard_local_gather(gather, dataplane)
                     if _shard_local_gather_ok(dataplane, config) else gather)
+    # Train batches are sharded like their starts whatever the gather's
+    # lowering: GSPMD replicates a Pallas kernel's output, and the model
+    # would then run on the whole batch on every device.
+    pin = dataplane.batch_sharding
 
-    def train_loss(params, starts):
-        s = jax.lax.with_sharding_constraint(series, pin) if pin else series
-        x, y = train_gather(s, starts, input_len=spec.in_len,
+    def train_loss(params, starts, series, loss):
+        x, y = train_gather(series, starts, input_len=spec.in_len,
                             horizon=spec.horizon)
-        return loss_fn(params, x, y)
+        if pin is not None:
+            x, y = jax.lax.with_sharding_constraint((x, y), pin)
+        return loss(params, x, y)
 
-    def eval_loss(params, starts):
-        s = jax.lax.with_sharding_constraint(series, pin) if pin else series
-        x, y = gather(s, starts, input_len=spec.in_len,
+    def eval_loss(params, starts, series, loss):
+        x, y = gather(series, starts, input_len=spec.in_len,
                       horizon=spec.horizon)
-        return loss_fn(params, x, y)
+        return loss(params, x, y)
 
     schedule = config.schedule or (lambda s: config.adam.lr)
     loop = config.loop
@@ -704,7 +741,14 @@ def _compile(dataplane: DataPlane, loss_fn: Callable, config: PipelineConfig):
         train_loss, config.adam, schedule,
         microbatches=loop.microbatches, grad_dtype=loop.grad_dtype,
         donate=loop.donate)
-    return train_step, jax.jit(eval_loss)
+    loss = _as_partial(loss_fn)
+    if dataplane.mesh.size > 1:
+        # Replicate the loss's arrays over the mesh once, here, instead of
+        # letting every call copy them from the device they were made on.
+        loss = jax.device_put(jax.tree.map(np.asarray, loss),
+                              NamedSharding(dataplane.mesh, P()))
+    args = (dataplane.dataset.series, loss)
+    return BoundStep(train_step, args), BoundStep(jax.jit(eval_loss), args)
 
 
 def build_engine(
@@ -722,7 +766,10 @@ def build_engine(
 
     ``loss_fn(params, x, y) -> (loss, metrics)`` is the only model-specific
     piece; the engine supplies (x, y) by fusing the selected window gather
-    into the jitted step.  Pass ``dataset=`` to reuse an already-built
+    into the jitted step.  Make it a ``jax.tree_util.Partial`` over the
+    model's constant arrays (``Partial(f, supports)`` with
+    ``f(supports, params, x, y)``): they then enter the step as arguments
+    instead of being embedded in the program.  Pass ``dataset=`` to reuse an already-built
     ``IndexDataset``; pass ``elastic=`` to survive worker loss mid-fit.
     """
     dataplane = build_dataplane(raw, spec, mesh, config, dataset=dataset)

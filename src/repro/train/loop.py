@@ -75,6 +75,8 @@ class TrainLoopConfig:
     prefetch_depth: int = 0
     staleness: int = 0
     prefetch_chunk: int = 8
+    # Stop after this many global steps (0 = run every epoch to its end).
+    max_steps: int = 0
 
 
 def combine_weighted(pairs) -> float:
@@ -211,21 +213,25 @@ def make_train_step(
 ):
     """Build the jitted train step.
 
-    loss_fn(params, batch) -> (loss, metrics).  ``batch`` is any pytree whose
-    leaves have a leading per-step batch dim (divisible by ``microbatches``).
-    Returns step(state, batch) -> (state, metrics).
+    loss_fn(params, batch, *args) -> (loss, metrics).  ``batch`` is any
+    pytree whose leaves have a leading per-step batch dim (divisible by
+    ``microbatches``); ``args`` are whole arrays every microbatch sees (the
+    resident series, the model's constants), passed to the step as jit
+    arguments and never donated.  Returns
+    step(state, batch, *args) -> (state, metrics).
     """
 
-    def grads_of(params, batch):
-        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
+    def grads_of(params, batch, args):
+        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch, *args)
         if grad_dtype is not None:
             grads = jax.tree.map(lambda g: g.astype(grad_dtype), grads)
         return loss, metrics, grads
 
-    def step(state, batch):
+    def step(state, batch, *args):
         params, opt_state = state["params"], state["opt"]
         if microbatches == 1:
-            loss, metrics, grads = grads_of(params, batch)
+            loss, metrics, grads = grads_of(params, batch, args)
         else:
             def slice_mb(i):
                 return jax.tree.map(
@@ -233,7 +239,7 @@ def make_train_step(
 
             def acc_step(carry, i):
                 loss_a, grads_a = carry
-                loss, _, grads = grads_of(params, slice_mb(i))
+                loss, _, grads = grads_of(params, slice_mb(i), args)
                 return (loss_a + loss,
                         jax.tree.map(jnp.add, grads_a, grads)), None
 
@@ -356,7 +362,15 @@ def run_training(
             sig.epoch, sig.step = epoch, global_step
             raise
 
+    # Where the run stops: the end of the last epoch, or the position of the
+    # ``max_steps``-th step (None when resuming at or past that step, so the
+    # checkpoint on disk already records the position).
+    final_meta: dict | None = {"epoch": loop.epochs, "done_in_epoch": 0}
+    capped = False
     for epoch in range(start_epoch, loop.epochs):
+        if loop.max_steps and global_step >= loop.max_steps:
+            final_meta = None
+            break
         if batch_stream is None:
             grid = grid_of_epoch(epoch)
             steps = grid.shape[0]
@@ -391,6 +405,9 @@ def run_training(
                     checkpointer.save(
                         state, step=global_step,
                         meta=epoch_meta(epoch, i + 1, steps))
+                if loop.max_steps and global_step >= loop.max_steps:
+                    final_meta, capped = epoch_meta(epoch, i + 1, steps), True
+                    break
                 if i < steps - 1:
                     check_health(i + 1, steps)
         finally:
@@ -409,13 +426,14 @@ def run_training(
                 and (epoch + 1) % loop.eval_every == 0:
             epoch_metrics.update(eval_fn(state))
         log_row(epoch_metrics)
+        if capped:
+            break
         # The final step's health poll runs AFTER the epoch summary: a
         # restart landing exactly on the epoch boundary would otherwise
         # abort before the summary/eval row and the resumed run — which
         # starts at the next epoch — could never emit it.
         check_health(steps, steps)
-    if checkpointer is not None:
-        checkpointer.save(state, step=global_step,
-                          meta={"epoch": loop.epochs, "done_in_epoch": 0})
+    if checkpointer is not None and final_meta is not None:
+        checkpointer.save(state, step=global_step, meta=final_meta)
         checkpointer.wait()
     return state, history

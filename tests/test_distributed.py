@@ -92,14 +92,12 @@ def test_checkpoint_meta_roundtrip(tmp_path):
 
 def test_elastic_restore_into_new_sharding(tmp_path):
     """Restart on a different topology: restore re-device_puts every leaf."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
     ck = Checkpointer(str(tmp_path), async_write=False)
     state = _tiny_state()
     ck.save(state, step=3)
-    mesh = make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = NamedSharding(mesh, P())
     restored, _ = restore(str(tmp_path), state, shardings=sh)
     leaf = restored["params"]["w"]

@@ -183,3 +183,35 @@ def test_halo_evidence_communication_free():
     assert rec["halo_false"]["all-reduce"] > 0  # grads still reduce
     assert rec["halo_true"]["data_bytes"] > 0
     assert rec["halo_true"]["counts"]["all-gather"] >= 1
+
+
+# ------------------------------------------------------------ compile cache
+def test_compile_cache_follows_env_var(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper configures nothing."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    """Without it, the cache is one fixed, git-ignored directory inside the
+    checkout — the same path on every call."""
+    import pathlib
+
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = enable_compile_cache()
+        assert first == enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert REPO_CACHE_DIR.parent == repo
+    ignored = (repo / ".gitignore").read_text().splitlines()
+    assert f"{REPO_CACHE_DIR.name}/" in ignored

@@ -526,3 +526,69 @@ def test_microbatched_step_keeps_bf16_grad_tree():
                           jnp.ones((4,), jnp.float32))
     assert state["params"]["w"].dtype == jnp.bfloat16
     assert np.isfinite(float(metrics["loss"]))
+
+
+# ------------------------------------------- resident arrays are arguments
+def test_train_step_takes_series_and_supports_as_arguments():
+    """The lowered train/eval programs take the resident series and the
+    loss's Partial-bound supports as arguments and embed neither: no
+    constant in them is as large as either array."""
+    from repro.launch.dryrun import largest_constant_bytes
+
+    nodes = 32
+    supports = (jnp.full((nodes, nodes), 1.0 / nodes, jnp.float32),
+                jnp.eye(nodes, dtype=jnp.float32))
+
+    def loss(sup, p, x, y):
+        h = jnp.einsum("mn,btnf->btmf", sup[0] @ sup[1], x)
+        return jnp.mean((h[:, -1] * p["w"] - y[:, 0]) ** 2), {}
+
+    pipe = build_pipeline(
+        make_traffic_series(ENTRIES, nodes), SPEC, make_host_mesh(),
+        jax.tree_util.Partial(loss, supports),
+        {"w": jnp.full((nodes, 2), 0.1, jnp.float32)},
+        PipelineConfig(batch_per_rank=B, adam=AdamConfig(lr=1e-2)))
+    state = init_train_state(pipe.init_params, pipe.config.adam)
+    starts = pipe.batch_of_starts(pipe.sampler.epoch_global(0)[0])
+    series = pipe.dataset.series
+    smallest = min(series.nbytes, supports[0].nbytes)
+    for lowered in (pipe.train_step.lower(state, starts),
+                    pipe._eval_loss.lower(state["params"], starts)):
+        shapes = [a.shape for a in jax.tree.leaves(lowered.args_info)]
+        assert series.shape in shapes
+        assert shapes.count((nodes, nodes)) == 2
+        assert largest_constant_bytes(lowered) < smallest
+
+
+def test_largest_constant_bytes_sees_a_closed_over_array():
+    from repro.launch.dryrun import largest_constant_bytes
+
+    big = jnp.ones((64, 32), jnp.float32)
+    lowered = jax.jit(lambda x: (x + big).sum()).lower(jnp.ones((64, 32)))
+    assert largest_constant_bytes(lowered) == big.nbytes
+
+
+@pytest.mark.parametrize("max_steps", [1, 7, 12])
+def test_max_steps_caps_training(max_steps, tmp_path):
+    """TrainLoopConfig.max_steps stops mid-epoch; the final checkpoint
+    records where, so a resume with a larger cap picks up from there."""
+    from repro.distributed import checkpoint_meta, latest_step
+
+    capped = build_pipeline(
+        make_traffic_series(ENTRIES, NODES), SPEC, make_host_mesh(),
+        _loss_fn, _params(),
+        PipelineConfig(
+            batch_per_rank=B, placement=Placement.REPLICATED, world=WORLD,
+            seed=11, adam=AdamConfig(lr=1e-2),
+            loop=TrainLoopConfig(epochs=3, log_every=1, max_steps=max_steps,
+                                 ckpt_dir=str(tmp_path))))
+    spe = capped.steps_per_epoch
+    assert spe < 12 < 3 * spe
+    state, history = capped.fit(eval_fn=None)
+    steps = [h["step"] for h in history if "epoch_time_s" not in h]
+    assert steps == list(range(1, max_steps + 1))
+    assert int(state["opt"]["step"]) == max_steps
+    assert latest_step(str(tmp_path)) == max_steps
+    epoch, done = divmod(max_steps, spe)
+    assert checkpoint_meta(str(tmp_path)) == {"epoch": epoch,
+                                              "done_in_epoch": done}
