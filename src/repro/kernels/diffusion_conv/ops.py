@@ -8,9 +8,11 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace_names
 from repro.kernels.common import kernel_defaults
 from repro.kernels.diffusion_conv.kernel import hop_project
 from repro.kernels.diffusion_conv.ref import diffusion_conv_ref
@@ -69,8 +71,9 @@ def diffusion_conv(
         s_p = _pad_nodes(s, n_pad, (0, 1))
         z = z0
         for k in range(k_hops):
-            z, y = hop_project(
-                s_p, z, wk[si, k].astype(x.dtype), y,
-                block_n=block_n, block_b=block_b, interpret=kd.interpret,
-            )
+            with jax.named_scope(trace_names.DCONV_HOP):
+                z, y = hop_project(
+                    s_p, z, wk[si, k].astype(x.dtype), y,
+                    block_n=block_n, block_b=block_b, interpret=kd.interpret,
+                )
     return y[:, :n] + b

@@ -5,7 +5,10 @@ each block of size C, so ``w: [(1 + n_supports*K) * C, H]``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+from repro import trace_names
 
 
 def diffusion_conv_ref(x, supports, w, b, *, k_hops: int):
@@ -14,7 +17,8 @@ def diffusion_conv_ref(x, supports, w, b, *, k_hops: int):
     for s in supports:
         z = x
         for _ in range(k_hops):
-            z = jnp.einsum("mn,bnc->bmc", s, z)
+            with jax.named_scope(trace_names.DCONV_HOP):
+                z = jnp.einsum("mn,bnc->bmc", s, z)
             feats.append(z)
     h = jnp.concatenate(feats, axis=-1)
     return h @ w + b

@@ -27,6 +27,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import trace_names
 from repro.kernels.diffusion_conv import diffusion_conv
 
 
@@ -93,6 +94,7 @@ def _dconv(p, cfg: DCRNNConfig, supports, x):
                           k_hops=cfg.max_diffusion_step, use_pallas=cfg.use_pallas)
 
 
+@jax.named_scope(trace_names.DCGRU)
 def dcgru_cell(p, cfg: DCRNNConfig, supports, x, h):
     """One DCGRU step.  x: [B, N, C], h: [B, N, H] -> new h."""
     xh = jnp.concatenate([x, h], axis=-1)
@@ -143,7 +145,8 @@ def apply(
         # (without this the scan saves every hop's [B, N, C] — measured
         # 209 GiB/device on the PeMS cell)
         enc_step = jax.checkpoint(enc_step)
-    hs, _ = jax.lax.scan(enc_step, h0, jnp.swapaxes(x_seq, 0, 1))
+    with jax.named_scope(trace_names.RNN_SCAN):
+        hs, _ = jax.lax.scan(enc_step, h0, jnp.swapaxes(x_seq, 0, 1))
 
     # ---- decoder: roll out horizon steps
     go = jnp.zeros((B, N, cfg.out_features), x_seq.dtype)
@@ -165,7 +168,8 @@ def apply(
 
     if cfg.remat:
         dec_step = jax.checkpoint(dec_step)
-    (_, _), outs = jax.lax.scan(dec_step, (hs, go), (coin, teach))
+    with jax.named_scope(trace_names.RNN_SCAN):
+        (_, _), outs = jax.lax.scan(dec_step, (hs, go), (coin, teach))
     return jnp.swapaxes(outs, 0, 1)  # [B, horizon, N, F_out]
 
 

@@ -16,6 +16,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import trace_names
 from repro.kernels.diffusion_conv import diffusion_conv
 
 
@@ -57,6 +58,7 @@ def init(rng, cfg: PGTDCRNNConfig) -> dict[str, Any]:
     }
 
 
+@jax.named_scope(trace_names.DCGRU)
 def _cell(params, cfg: PGTDCRNNConfig, supports, x, h):
     xh = jnp.concatenate([x, h], axis=-1)
     ru = jax.nn.sigmoid(
@@ -82,7 +84,8 @@ def apply(params, cfg: PGTDCRNNConfig, supports, x_seq: jnp.ndarray) -> jnp.ndar
 
     if cfg.remat:
         step = jax.checkpoint(step)
-    _, outs = jax.lax.scan(step, h0, jnp.swapaxes(x_seq, 0, 1))
+    with jax.named_scope(trace_names.RNN_SCAN):
+        _, outs = jax.lax.scan(step, h0, jnp.swapaxes(x_seq, 0, 1))
     return jnp.swapaxes(outs, 0, 1)
 
 

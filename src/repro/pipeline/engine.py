@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import trace_names
 from repro.core.distributed import Placement, data_axes, dp_size
 from repro.core.index_dataset import IndexDataset
 from repro.core.windows import WindowSpec
@@ -251,6 +252,10 @@ class Engine:
                 "would hang; use ElasticConfig(remesh='relaunch') so the "
                 "launcher tears the gang down and relaunches into the "
                 "planned topology (see tests/multihost.py)")
+        # Open until run_training's first step: the state below, a restore,
+        # and the first epoch's prefetcher.
+        start_span = jax.profiler.TraceAnnotation(trace_names.FIT_START)
+        start_span.__enter__()
         # Copy params into the fresh state: the jitted step donates its state
         # argument, and aliasing the caller's arrays would delete them after
         # the first step (breaking re-fits and sibling pipelines).
@@ -343,6 +348,7 @@ class Engine:
                     health_cb=self._health_cb(monitor),
                     history_sink=history_sink,
                     batch_stream=batch_stream,
+                    start_span=start_span,
                 )
                 history.extend(hist)
                 return state, history
@@ -724,15 +730,17 @@ def _compile(dataplane: DataPlane, loss_fn: Callable, config: PipelineConfig):
     pin = dataplane.batch_sharding
 
     def train_loss(params, starts, series, loss):
-        x, y = train_gather(series, starts, input_len=spec.in_len,
-                            horizon=spec.horizon)
-        if pin is not None:
-            x, y = jax.lax.with_sharding_constraint((x, y), pin)
+        with jax.named_scope(trace_names.GATHER):
+            x, y = train_gather(series, starts, input_len=spec.in_len,
+                                horizon=spec.horizon)
+            if pin is not None:
+                x, y = jax.lax.with_sharding_constraint((x, y), pin)
         return loss(params, x, y)
 
     def eval_loss(params, starts, series, loss):
-        x, y = gather(series, starts, input_len=spec.in_len,
-                      horizon=spec.horizon)
+        with jax.named_scope(trace_names.GATHER):
+            x, y = gather(series, starts, input_len=spec.in_len,
+                          horizon=spec.horizon)
         return loss(params, x, y)
 
     schedule = config.schedule or (lambda s: config.adam.lr)

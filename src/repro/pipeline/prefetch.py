@@ -33,8 +33,9 @@ loss.  This module is that pipeline, in two explicit stages:
     still identical (pure feeds); what changes is only *when* host/transfer
     work happens relative to the step stream.
 
-Threading rules: stage 1 never touches jax (numpy only), so it is safe to
-start before ``jax.distributed.initialize()`` has run.  Stage 2 calls the
+Threading rules: stage 1 does numpy work only (its ``feed.build`` host span
+initialises no backend), so it is safe to start before
+``jax.distributed.initialize()`` has run.  Stage 2 calls the
 transfer fn (``device_put`` / ``make_array_from_process_local_data``) from
 its own thread — process-local calls with no collectives, safe under
 ``jax.distributed`` — and only exists at staleness >= 1.  Kernel-level
@@ -54,7 +55,10 @@ import threading
 import time
 from typing import Any, Callable, Iterator
 
+import jax
 import numpy as np
+
+from repro import trace_names
 
 #: End-of-stream marker flowing through the stage queues.
 _DONE = object()
@@ -142,11 +146,15 @@ class FeedPrefetcher:
 
     def _host_stage(self, rows: Iterator[np.ndarray]) -> None:
         try:
-            for block in rows:
+            rows = iter(rows)
+            while True:
+                with jax.profiler.TraceAnnotation(trace_names.FEED_BUILD):
+                    block = next(rows, _DONE)
+                if block is _DONE:
+                    self._put(self._host_q, _DONE)
+                    break
                 if self._stop.is_set() or not self._put(self._host_q, block):
                     break
-            else:
-                self._put(self._host_q, _DONE)
         except BaseException as e:  # surfaced to the consumer in __next__
             self._error = e
             self._put(self._host_q, _DONE)
@@ -166,7 +174,10 @@ class FeedPrefetcher:
                     self._put(self._dev_q, _DONE)
                     return
                 for row in block:
-                    if not self._put(self._dev_q, self._transfer(row)):
+                    with jax.profiler.TraceAnnotation(
+                            trace_names.FEED_TRANSFER):
+                        batch = self._transfer(row)
+                    if not self._put(self._dev_q, batch):
                         return
             # closed mid-stream: nothing more to do
         except BaseException as e:
@@ -189,21 +200,23 @@ class FeedPrefetcher:
                 if block is _DONE:
                     return self._finish()
                 self._pending = list(block)
-            return self._transfer(self._pending.pop(0))
+            with jax.profiler.TraceAnnotation(trace_names.FEED_TRANSFER):
+                return self._transfer(self._pending.pop(0))
         batch = self._get(src)
         if batch is _DONE:
             return self._finish()
         return batch
 
     def _get(self, q: queue.Queue):
-        while True:
-            if self._stop.is_set():
-                return _DONE
-            try:
-                return q.get(timeout=_TICK)
-            except queue.Empty:
-                if self._error is not None:
+        with jax.profiler.TraceAnnotation(trace_names.FEED_WAIT):
+            while True:
+                if self._stop.is_set():
                     return _DONE
+                try:
+                    return q.get(timeout=_TICK)
+                except queue.Empty:
+                    if self._error is not None:
+                        return _DONE
 
     def _finish(self):
         self._finished = True

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace_names
 from repro.optim import AdamConfig, apply_updates, init_opt_state
 
 
@@ -249,8 +250,10 @@ def make_train_step(
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             metrics = {}
-        lr = schedule(opt_state["step"])
-        new_params, new_opt, gnorm = apply_updates(params, grads, opt_state, adam, lr)
+        with jax.named_scope(trace_names.OPTIMIZER):
+            lr = schedule(opt_state["step"])
+            new_params, new_opt, gnorm = apply_updates(
+                params, grads, opt_state, adam, lr)
         out_metrics = {"loss": loss, "lr": lr, **metrics}
         if gnorm is not None:
             out_metrics["grad_norm"] = gnorm
@@ -283,6 +286,7 @@ def run_training(
     health_cb: Callable[[int], None] | None = None,
     history_sink: list | None = None,
     batch_stream: Callable[[int, int], Any] | None = None,
+    start_span=None,
 ) -> tuple[Any, list[dict]]:
     """Generic epoch loop.
 
@@ -328,8 +332,15 @@ def run_training(
     a ``close()`` it is drained on every exit from the epoch, normal or
     not — in particular on :class:`RestartSignal`, so an elastic re-mesh
     never leaves stale in-flight batches behind.
+
+    Host spans (``repro.trace_names``) mark the feed, the dispatch, the
+    logging sync, checkpoints, health polls and evaluation; ``fit.end`` runs
+    from the last step's bookkeeping to the return.  ``start_span``, an entered
+    ``jax.profiler.TraceAnnotation``, is exited before the first step's feed.
     """
+    span = jax.profiler.TraceAnnotation
     history: list[dict] = []
+    end_span = None
     global_step = start_step
     grid_of_epoch = getattr(sampler, "epoch_grid", sampler.epoch_global)
 
@@ -352,12 +363,14 @@ def run_training(
         if health_cb is None:
             return
         try:
-            health_cb(global_step)
+            with span(trace_names.LOOP_HEALTH):
+                health_cb(global_step)
         except RestartSignal as sig:
             if checkpointer is not None:
-                checkpointer.save(state, step=global_step,
-                                  meta=epoch_meta(epoch, done_now, steps))
-                checkpointer.wait()
+                with span(trace_names.LOOP_CKPT):
+                    checkpointer.save(state, step=global_step,
+                                      meta=epoch_meta(epoch, done_now, steps))
+                    checkpointer.wait()
             sig.state, sig.history = state, history
             sig.epoch, sig.step = epoch, global_step
             raise
@@ -393,20 +406,34 @@ def run_training(
                    else None)
         try:
             for i in range(done_in_epoch, steps):
-                batch = (next(batches) if batches is not None
-                         else batch_of_starts(grid[i]))
-                state, metrics = train_step(state, batch)
+                if start_span is not None:
+                    start_span.__exit__(None, None, None)
+                    start_span = None
+                with span(trace_names.FEED_NEXT):
+                    if batches is not None:
+                        batch = next(batches)
+                    else:
+                        with span(trace_names.FEED_TRANSFER):
+                            batch = batch_of_starts(grid[i])
+                with span(trace_names.STEP_DISPATCH):
+                    state, metrics = train_step(state, batch)
                 global_step += 1
                 if loop.log_every and global_step % loop.log_every == 0:
-                    m = {k: float(v) for k, v in metrics.items()}
+                    with span(trace_names.LOOP_LOG_SYNC):
+                        m = {k: float(v) for k, v in metrics.items()}
                     log_row({"step": global_step, "epoch": epoch, **m})
                 if (checkpointer is not None and loop.ckpt_every
                         and global_step % loop.ckpt_every == 0):
-                    checkpointer.save(
-                        state, step=global_step,
-                        meta=epoch_meta(epoch, i + 1, steps))
+                    with span(trace_names.LOOP_CKPT):
+                        checkpointer.save(
+                            state, step=global_step,
+                            meta=epoch_meta(epoch, i + 1, steps))
                 if loop.max_steps and global_step >= loop.max_steps:
                     final_meta, capped = epoch_meta(epoch, i + 1, steps), True
+                if capped or (i == steps - 1 and epoch == loop.epochs - 1):
+                    end_span = span(trace_names.FIT_END)
+                    end_span.__enter__()
+                if capped:
                     break
                 if i < steps - 1:
                     check_health(i + 1, steps)
@@ -424,7 +451,8 @@ def run_training(
                          "loss": float(metrics["loss"])}
         if eval_fn is not None and loop.eval_every \
                 and (epoch + 1) % loop.eval_every == 0:
-            epoch_metrics.update(eval_fn(state))
+            with span(trace_names.LOOP_EVAL):
+                epoch_metrics.update(eval_fn(state))
         log_row(epoch_metrics)
         if capped:
             break
@@ -434,6 +462,10 @@ def run_training(
         # starts at the next epoch — could never emit it.
         check_health(steps, steps)
     if checkpointer is not None and final_meta is not None:
-        checkpointer.save(state, step=global_step, meta=final_meta)
-        checkpointer.wait()
+        with span(trace_names.LOOP_CKPT):
+            checkpointer.save(state, step=global_step, meta=final_meta)
+            checkpointer.wait()
+    for open_span in (start_span, end_span):
+        if open_span is not None:
+            open_span.__exit__(None, None, None)
     return state, history
