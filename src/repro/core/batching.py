@@ -39,12 +39,18 @@ def gather_batch(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Index-batching: (x, y) for a batch of window starts, gathered on-device.
 
-    series: [T, ...]   starts: [B] int32
+    series: [T, ...]   starts: [B] int32, each at most ``T - input_len - horizon``
     returns x: [B, input_len, ...], y: [B, horizon, ...]
+
+    Each window's whole span is sliced once and split.  A separate x-only
+    gather would feed nothing but the model's default-precision matmuls, so
+    the compiler would move their bf16 conversion above the gather, where it
+    no longer depends on the start, and hoist it out of the gather as a bf16
+    copy of the whole resident series made every step.  The span also feeds
+    y, which the loss reads in float32, so the conversion stays on x alone.
     """
-    x = jax.vmap(lambda s: _window(series, s, input_len))(starts)
-    y = jax.vmap(lambda s: _window(series, s + input_len, horizon))(starts)
-    return x, y
+    w = jax.vmap(lambda s: _window(series, s, input_len + horizon))(starts)
+    return w[:, :input_len], w[:, input_len:]
 
 
 def gather_batch_take(

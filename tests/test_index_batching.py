@@ -42,6 +42,22 @@ def test_index_equals_materialized(case):
     assert np.array_equal(ys, np.asarray(yg))
 
 
+@pytest.mark.parametrize("in_len,hor", [(12, 12), (1, 5), (5, 1)])
+@pytest.mark.parametrize("edge", ["first", "last"])
+def test_gather_batch_at_edge_starts(in_len, hor, edge):
+    """The first and the last valid start (``T - input_len - horizon``): the
+    span slice is not clamped, so x and y equal the Alg.-1 snapshot."""
+    t = 40
+    series = np.random.default_rng(3).standard_normal((t, 4, 2)).astype(np.float32)
+    start = 0 if edge == "first" else t - in_len - hor
+    starts = np.full(3, start, np.int32)
+    xs, ys = materialize_windows(series, starts, in_len, hor)
+    xg, yg = gather_batch(jnp.asarray(series), jnp.asarray(starts),
+                          input_len=in_len, horizon=hor)
+    assert np.array_equal(xs, np.asarray(xg))
+    assert np.array_equal(ys, np.asarray(yg))
+
+
 @given(window_case())
 @settings(max_examples=30, deadline=None)
 def test_gather_variants_agree(case):

@@ -12,6 +12,11 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test collection happens in
 every worker.
 """
+import dataclasses
+import functools
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +31,7 @@ from repro.kernels.diffusion_conv import diffusion_conv
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_scan import linear_scan
 from repro.kernels.window_gather import window_gather
-from repro.models import pgt_dcrnn
+from repro.models import dcrnn, pgt_dcrnn
 from repro.optim import AdamConfig
 from repro.pipeline.dataplane import DataPlane, PipelineConfig
 from repro.pipeline.engine import _compile
@@ -101,15 +106,27 @@ def test_flash_attention_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_pgt_dcrnn_train_step_fits_one_chip(topo, one_chip):
-    """The engine's train step at batch 64 over the resident Table-1 series:
-    arguments (series, supports, state) plus temporaries fit 16 GiB."""
-    cfg = get_arch("pgt-dcrnn-pems-all-la").model
-    spec = WindowSpec(horizon=cfg.horizon, input_len=cfg.input_len)
+@pytest.fixture(scope="module")
+def train_step(topo, one_chip):
+    """``compiled(model, batch, gather)``: the engine's train step, built by
+    ``pipeline.engine._compile``, compiled for one described chip over the
+    resident Table-1 series at PeMS-All-LA width; each case compiled once."""
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+
+    @functools.cache
+    def compiled(model, batch, gather):
+        return _compile_train_step(mesh, one_chip, model, batch, gather)
+
+    return compiled
+
+
+def _compile_train_step(mesh, one_chip, model, batch, gather):
+    module, cfg = _MODELS[model]()
+    spec = WindowSpec(horizon=cfg.horizon, input_len=cfg.input_len)
     series = _sds((ENTRIES, NODES, FEATURES), jnp.float32, one_chip)
     sup = (_sds((NODES, NODES), jnp.float32, one_chip),) * 2
-    config = PipelineConfig(batch_per_rank=64, adam=AdamConfig())
+    config = PipelineConfig(batch_per_rank=batch, gather=gather,
+                            adam=AdamConfig())
     dataset = IndexDataset(series=series, starts=np.zeros(1, np.int32),
                            spec=spec, scaler=None, train_windows=None,
                            val_windows=None, test_windows=None)
@@ -120,15 +137,46 @@ def test_pgt_dcrnn_train_step_fits_one_chip(topo, one_chip):
                       world=1, batch_sharding=None)
 
     def loss(supports, p, x, y):
-        return pgt_dcrnn.loss_fn(p, cfg, supports, x, y), {}
+        return module.loss_fn(p, cfg, supports, x, y), {}
 
-    train_step, _ = _compile(plane, jax.tree_util.Partial(loss, sup), config)
+    step, _ = _compile(plane, jax.tree_util.Partial(loss, sup), config)
     state = jax.eval_shape(lambda: init_train_state(
-        pgt_dcrnn.init(jax.random.PRNGKey(0), cfg), config.adam))
+        module.init(jax.random.PRNGKey(0), cfg), config.adam))
     state = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), state)
-    lowered = train_step.lower(state, _sds((64,), jnp.int32, one_chip))
-    mem = lowered.compile().memory_analysis()
+    return step.lower(state, _sds((batch,), jnp.int32, one_chip)).compile()
+
+
+_MODELS = {
+    "pgt_dcrnn": lambda: (pgt_dcrnn, get_arch("pgt-dcrnn-pems-all-la").model),
+    # dcrnn-pems's encoder-decoder with remat, as its benchmark cell runs it,
+    # at PeMS-All-LA width so the compile stays short.
+    "dcrnn": lambda: (dcrnn, dataclasses.replace(
+        get_arch("dcrnn-pems").model, num_nodes=NODES, remat=True)),
+}
+
+
+def test_pgt_dcrnn_train_step_fits_one_chip(train_step):
+    """The engine's train step at batch 64 over the resident Table-1 series:
+    arguments (series, supports, state) plus temporaries fit 16 GiB."""
+    mem = train_step("pgt_dcrnn", 64, "slice").memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes >= ENTRIES * NODES * FEATURES * 4
     assert used < HBM_BYTES, (mem.argument_size_in_bytes,
                               mem.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("model,batch,gather", [
+    ("pgt_dcrnn", 64, "slice"),
+    ("pgt_dcrnn", 64, "fused"),
+    ("pgt_dcrnn", 64, "take"),
+    ("dcrnn", 8, "slice"),
+])
+def test_train_step_keeps_no_bf16_series_copy(train_step, model, batch,
+                                              gather):
+    """The matmuls round their inputs to bf16; the compiled step converts the
+    gathered windows, never the whole resident series once per step."""
+    series_elems = ENTRIES * NODES * FEATURES
+    copies = [m.group(0) for m in re.finditer(
+        r"bf16\[([0-9,]+)\]", train_step(model, batch, gather).as_text())
+        if math.prod(map(int, m.group(1).split(","))) == series_elems]
+    assert not copies, copies
