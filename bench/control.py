@@ -5,7 +5,9 @@
 
 For every seed it builds the cell at its own size, runs the warm-up steps
 through ``Engine.fit`` exactly as a benchmark run does, and compares them with
-the plain reference: the program's readings (the lower end of each limit).
+the plain reference, at the block and matmul precision the cell's checks file
+gives (``harness.reference_args``): the program's readings (the lower end of
+each limit).
 For the control seeds it also reads, against the same reference:
 
 - ``control``: the reference put in the program's place, computed in
@@ -52,7 +54,7 @@ def main() -> int:
     harness._require_chips(w["chips"])
     cfg, traffic = spec.config(w["config"]), spec.traffic(w["traffic"])
     checks = spec.checks(args.workload)
-    block = checks["reference_block"]
+    ref_args = harness.reference_args(checks)
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
@@ -66,7 +68,7 @@ def main() -> int:
         prog, parts = s.prog, s.parts
         del s, series
         gc.collect()
-        ref = harness.follow_reference(cfg, parts, batches, block=block)
+        ref = harness.follow_reference(cfg, parts, batches, **ref_args)
         out = {"seed": seed, "reading": "program",
                **harness.readings(cfg, prog, ref),
                "losses": prog["losses"], "ref_losses": ref["losses"]}
@@ -82,8 +84,7 @@ def main() -> int:
                 variants["one_shard"] = dict(rows=slice(0, b // w["chips"]))
             for name, kw in variants.items():
                 got = harness.follow_reference(
-                    cfg, parts, kw.pop("batches", batches),
-                    block=block, **kw)
+                    cfg, parts, kw.pop("batches", batches), **{**ref_args, **kw})
                 print(json.dumps({"seed": seed, "reading": name,
                                   **harness.readings(cfg, _as_program(got), ref)}),
                       flush=True)

@@ -301,11 +301,12 @@ def _worst_leaf(prog: list[float], ref: list[float], keep: list[bool]) -> float:
 
 
 def window_flops(cfg: dict, nnz: list[int]) -> tuple[float, float]:
-    """(useful, dense) FLOPs of one training window of this configuration."""
+    """(useful, dense) FLOPs of one training window of this configuration,
+    as its family's plain reference counts them."""
     from bench.reference import train as ref_train
-    mod, m = ref_train.model(cfg["family"]), cfg["model"]
-    return tuple(flops.window_flops(m, mod.cells(m), mod.readouts(m), nnz,
-                                    dense=dense) for dense in (False, True))
+    mod = ref_train.model(cfg["family"])
+    return tuple(mod.window_flops(cfg["model"], nnz, dense=dense)
+                 for dense in (False, True))
 
 
 def reference_batches(series, starts: list[np.ndarray], m: dict):
@@ -361,6 +362,15 @@ def follow_reference(cfg: dict, parts: dict, batches, *, block: int,
                            precision=precision)
     out["params0"] = params0
     return out
+
+
+def reference_args(checks: dict) -> dict:
+    """The reference's block of rows and matmul precision, from a cell's
+    checks file: ``highest`` unless the file names another, such as the
+    configuration's own ``default`` where a reference at ``highest`` sits
+    as far from the program as the lower-precision control does."""
+    return {"block": checks["reference_block"],
+            "precision": checks.get("reference_precision", "highest")}
 
 
 def readings(cfg: dict, prog: dict, ref: dict) -> dict:
@@ -502,7 +512,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     del state, batch, pipe, s
     gc.collect()
     t_ref = time.perf_counter()
-    ref = follow_reference(cfg, parts, batches, block=checks["reference_block"])
+    ref = follow_reference(cfg, parts, batches, **reference_args(checks))
     got = readings(cfg, prog, ref)
     got.update(duplicate_windows=duplicates, window_failed=failed)
     log(f"reference: {len(batches)} steps in {time.perf_counter() - t_ref:.2f} s; "

@@ -1,12 +1,18 @@
 """The least time of a train step's diffusion hops on one chip: the floor of
 a hop roofline share (least time over the measured hop time).
 
+DCGRU models only: each cell's input width and calls per window come from
+the DCGRU references' ``cells(m)`` (``bench/reference/pgt_dcrnn.py`` and
+``dcrnn.py``), the hops per support from ``max_diffusion_step``.  Other
+families have no ``cells``.
+
 A hop ``Z_k = S Z_{k-1}`` over a batch of ``B`` windows of ``N`` nodes and
 ``C`` channels, for each of a DCGRU cell's two diffusion convolutions, ``K``
 hops per support:
 
-- FLOPs: ``2 nnz(S) C`` per window forward, as ``bench/flops.py`` counts
-  them, and the 1x backward ``S^T dZ_k`` (the supports take no gradient).
+- FLOPs: ``2 nnz(S) C`` per window forward, as ``bench/reference/dcgru.py``
+  counts them, and the 1x backward ``S^T dZ_k`` (the supports take no
+  gradient).
 - bytes: the support read once per hop for the batch, at least its nonzeros
   as a 4-byte value and a 4-byte column index (``8 nnz(S)``); ``Z_{k-1}``
   read and ``Z_k`` written, ``[B, N, C]`` float32 each (``8 B N C``).  The

@@ -75,7 +75,7 @@ def loss(params, m: dict, supports, x, y):
 
 def cells(m: dict) -> list[tuple[int, int]]:
     """``(input width, calls per window)`` of every DCGRU cell the model runs
-    for one window, for the FLOP count."""
+    for one window, for the FLOP count and ``bench/hop_roofline.py``."""
     h, deep = m["hidden"], m["layers"] - 1
     return [(m["in_features"] + h, m["input_len"]),
             (2 * h, deep * m["input_len"]),
@@ -83,6 +83,8 @@ def cells(m: dict) -> list[tuple[int, int]]:
             (2 * h, deep * m["horizon"])]
 
 
-def readouts(m: dict) -> int:
-    """Read-out projections (hidden -> out) per window."""
-    return m["horizon"]
+def window_flops(m: dict, nnz: list[int], *, dense: bool = False) -> float:
+    """Useful FLOPs of one training window, forward plus backward, the
+    supports counted at ``nnz`` nonzeros (``dense``: all ``N^2``); the
+    read-out runs once per output step."""
+    return dcgru.window_flops(m, cells(m), m["horizon"], nnz, dense=dense)

@@ -48,10 +48,12 @@ def loss(params, m: dict, supports, x, y):
 
 def cells(m: dict) -> list[tuple[int, int]]:
     """``(input width, calls per window)`` of every DCGRU cell the model runs
-    for one window, for the FLOP count."""
+    for one window, for the FLOP count and ``bench/hop_roofline.py``."""
     return [(m["in_features"] + m["hidden"], m["input_len"])]
 
 
-def readouts(m: dict) -> int:
-    """Read-out projections (hidden -> out) per window."""
-    return m["input_len"]
+def window_flops(m: dict, nnz: list[int], *, dense: bool = False) -> float:
+    """Useful FLOPs of one training window, forward plus backward, the
+    supports counted at ``nnz`` nonzeros (``dense``: all ``N^2``); the
+    read-out runs once per input step."""
+    return dcgru.window_flops(m, cells(m), m["input_len"], nnz, dense=dense)

@@ -12,7 +12,22 @@ configuration and its traffic mix; each is a file of its own under ``bench/``.
 ==========================  ==================================================
 
 Adding a configuration, a traffic mix or a metric adds files and entries in
-``BENCHMARK.json``; nothing here or in the harness changes.
+``BENCHMARK.json``; nothing here or in the harness changes.  So does adding a
+model family: a configuration names its program (``program.module``, whose
+class ``program.config`` takes the ``model`` dict) and its ``family``, and
+
+- the program module has ``init(key, cfg)`` and
+  ``loss_fn(params, cfg, supports, x, y)``;
+- the reference module ``bench/reference/<family>.py`` has ``init(key, m)``
+  and ``loss(params, m, supports, x, y)``, drawing the program's weights from
+  the same key with no code of the program, and
+  ``window_flops(m, nnz, *, dense) -> float``, the useful FLOPs of one
+  training window, forward and backward, with the supports at ``nnz``
+  nonzeros (``dense``: every entry), which the harness hands the metrics'
+  readers.
+
+``m`` is the configuration's ``model`` dict; ``x`` and ``y`` are
+``[B, T, N, F]`` windows of the series and ``supports`` the graph's.
 """
 from __future__ import annotations
 

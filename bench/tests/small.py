@@ -12,30 +12,15 @@ from bench import harness, spec
 NODES, ENTRIES, CHUNK = 40, 2400, 150
 
 
-#: Distributed-index-batching on four devices: the configuration of the
-#: one-chip PeMS-All-LA cell, time-sharded (PARTITIONED, no halo), 8 windows
-#: per device.  Not a cell of ``BENCHMARK.json`` yet.
-FOUR = {"name": "pgt-dcrnn-all-la.part4", "config": "pgt-dcrnn-all-la",
-        "traffic": None, "chips": 4, "checks": "pgt-dcrnn-all-la.b64",
-        "traffic_data": {"global_batch": 32, "placement": "partitioned",
-                         "gather": "slice", "halo": False}}
-
-
-def _workload(cell: str) -> dict:
-    return FOUR if cell == FOUR["name"] else spec.workload(cell)
-
-
 def config(cell: str) -> dict:
-    cfg = copy.deepcopy(spec.config(_workload(cell)["config"]))
+    cfg = copy.deepcopy(spec.config(spec.workload(cell)["config"]))
     cfg["model"]["num_nodes"] = NODES
     cfg["series"].update(entries=ENTRIES, chunk=CHUNK)
     return cfg
 
 
 def traffic(cell: str) -> dict:
-    w = _workload(cell)
-    if w is FOUR:
-        return dict(FOUR["traffic_data"])
+    w = spec.workload(cell)
     return dict(spec.traffic(w["traffic"]), global_batch=8 * w["chips"])
 
 
@@ -62,8 +47,6 @@ def run(cell: str, seed: int = 3_000_000_017, *, trace: bool = False) -> dict:
     with jax_config_kept(), mock.patch(
             "repro.launch.compile_cache.enable_compile_cache",
             lambda: "off in the tests"):
-        w = _workload(cell)
         return harness.run(cell, seed, 0.2, trace, t_start=time.perf_counter(),
-                           require_tpu=False, workload=w, cfg=config(cell),
-                           traffic=traffic(cell),
-                           checks=spec.checks(w.get("checks", cell)))
+                           require_tpu=False, cfg=config(cell),
+                           traffic=traffic(cell))

@@ -11,10 +11,13 @@ from bench import harness, spec
 from bench.tests import small
 
 
-@pytest.mark.parametrize("cell", ["pgt-dcrnn-all-la.b64", "dcrnn-pems.b8"])
+@pytest.mark.parametrize("cell", ["pgt-dcrnn-all-la.b64", "dcrnn-pems.b8",
+                                  "pgt-dcrnn-all-la.part4.b256"])
 def test_bfloat16_control_is_not_correct(cell):
     cfg, traffic = small.config(cell), small.traffic(cell)
-    limits = spec.checks(cell)["limits"]
+    checks = spec.checks(cell)
+    limits, ref_args = checks["limits"], dict(harness.reference_args(checks),
+                                              block=8)
     with small.jax_config_kept(), mock.patch(
             "repro.launch.compile_cache.enable_compile_cache", lambda: "off"):
         s = harness.setup(cfg, traffic, 2**31 + 99, cell)
@@ -23,7 +26,7 @@ def test_bfloat16_control_is_not_correct(cell):
         prog, parts = s.prog, s.parts
         del s
         gc.collect()
-        ref = harness.follow_reference(cfg, parts, batches, block=8)
+        ref = harness.follow_reference(cfg, parts, batches, **ref_args)
         sound = harness.readings(cfg, prog, ref)
         low = harness.follow_reference(cfg, parts, batches, block=8,
                                        dtype="bfloat16", precision="default")

@@ -110,7 +110,7 @@ print(json.dumps(small.run({cell!r})))
 def test_four_chip_cell_on_four_cpu_devices(fault):
     """PARTITIONED on four CPU devices: its time-sharded series and
     global-index gather are correct, and the exchange left out is not."""
-    cell = small.FOUR["name"]
+    cell = "pgt-dcrnn-all-la.part4.b256"
     root = spec.ROOT
     code = FOUR_DEVICES.format(paths=[root, os.path.join(root, "src")],
                                fault=fault, cell=cell)

@@ -1,10 +1,13 @@
-"""The benchmark's FLOP count against XLA's, and the table of peaks."""
+"""The families' FLOP counts against XLA's and against the counts the
+benchmark read before each family kept its own, and the table of peaks."""
 import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import flops
+from bench import flops, spec
 from bench.reference import dcgru
+from bench.reference import train as ref_train
+from bench.tests import small
 
 
 @pytest.mark.parametrize("nodes,c_in,hidden,k", [(48, 10, 8, 2), (64, 66, 64, 2),
@@ -27,7 +30,7 @@ def test_dense_cell_forward_matches_xla_cost_analysis(nodes, c_in, hidden, k):
     cost = jax.jit(lambda p, s, x, h: dcgru.cell(p, s, x, h, k)).lower(
         p, sup, x, h).compile().cost_analysis()
     xla = cost["flops"] if isinstance(cost, dict) else cost[0]["flops"]
-    hops, proj = flops.cell_forward(nodes, c_in, hidden, k, [nodes * nodes] * 2)
+    hops, proj = dcgru.cell_forward(nodes, c_in, hidden, k, [nodes * nodes] * 2)
     ours = hops + proj
     elementwise = 8.0 * nodes * 3 * hidden
     assert 0 <= xla - ours <= elementwise, (xla, ours)
@@ -37,12 +40,38 @@ def test_window_flops_counts_hops_on_nonzeros():
     m = {"num_nodes": 100, "hidden": 8, "max_diffusion_step": 2,
          "out_features": 1}
     cells = [(10, 12)]
-    dense = flops.window_flops(m, cells, 12, [5000, 5000], dense=True)
-    sparse = flops.window_flops(m, cells, 12, [5000, 5000])
-    full = flops.window_flops(m, cells, 12, [10000, 10000])
+    dense = dcgru.window_flops(m, cells, 12, [5000, 5000], dense=True)
+    sparse = dcgru.window_flops(m, cells, 12, [5000, 5000])
+    full = dcgru.window_flops(m, cells, 12, [10000, 10000])
     assert dense == full and sparse < dense
-    hops_dense = 2 * 12 * flops.cell_forward(100, 10, 8, 2, [10000] * 2)[0]
+    hops_dense = 2 * 12 * dcgru.cell_forward(100, 10, 8, 2, [10000] * 2)[0]
     assert dense - sparse == pytest.approx(hops_dense / 2)
+
+
+#: ``(configuration, size, nnz, dense, FLOPs)`` as ``bench/flops.py``'s
+#: ``window_flops`` counted them before the count moved into the references,
+#: so that ``step.mfu`` reads the same for the same windows/s.
+BEFORE = [
+    ("pgt-dcrnn-all-la", "published", [2980836, 2980836], False, 87948997632.0),
+    ("pgt-dcrnn-all-la", "published", [2980836, 2980836], True, 199356659712.0),
+    ("pgt-dcrnn-all-la", "small", [952, 950], False, 206763264.0),
+    ("pgt-dcrnn-all-la", "small", [952, 950], True, 223211520.0),
+    ("dcrnn-pems", "published", [50329316, 49000007], False, 7679141191872.0),
+    ("dcrnn-pems", "published", [50329316, 49000007], True, 18807047700480.0),
+    ("dcrnn-pems", "small", [952, 950], False, 1211488128.0),
+    ("dcrnn-pems", "small", [952, 950], True, 1307934720.0),
+]
+
+
+@pytest.mark.parametrize("name,size,nnz,dense,want", BEFORE)
+def test_dcgru_window_flops_are_the_counts_read_before(name, size, nnz, dense,
+                                                       want):
+    cfg = spec.config(name)
+    m = dict(cfg["model"])
+    if size == "small":
+        m["num_nodes"] = small.NODES
+    got = ref_train.model(cfg["family"]).window_flops(m, nnz, dense=dense)
+    assert got == want
 
 
 def test_peak_of_a_known_chip_and_an_unknown_one():

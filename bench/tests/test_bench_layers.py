@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import flops, harness, hop_roofline
+from bench import harness, hop_roofline
 from bench import trace_layers as L
 from bench import trace_reduce as T
 from bench.reference import train as ref_train
@@ -91,10 +91,10 @@ def test_scopes_reach_every_part_of_the_compiled_step(cell, remat):
                                          ("dcrnn", "dcrnn-pems.b8")])
 def test_hop_flops_are_the_hop_part_of_the_window_count(family, cell):
     m = small.config(cell)["model"]
-    cells = ref_train.model(family).cells(m)
+    mod = ref_train.model(family)
+    cells = mod.cells(m)
     nnz = [500, 700]
-    hops = (flops.window_flops(m, cells, 12, nnz)
-            - flops.window_flops(m, cells, 12, [0, 0]))
+    hops = mod.window_flops(m, nnz) - mod.window_flops(m, [0, 0])
     assert hop_roofline.hop_flops(m, cells, nnz, 3) == pytest.approx(3 * hops)
 
 

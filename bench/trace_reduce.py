@@ -15,8 +15,9 @@ clock.
   share = 1 - busy / window;
 - device operations: each operation name's self time (its duration less
   that of the operations nested in it), averaged over devices, longest first;
-- collectives: the union of the intervals, on either line, of operations
-  whose name begins with a collective's HLO opcode, per step;
+- collectives: on the first device, the union of the intervals, on either
+  line, of operations whose name begins with a collective's HLO opcode, per
+  step;
 - idle gaps: on the first device, each interval of the window in which it
   ran nothing, named by the innermost harness span open on the host at its
   midpoint.
@@ -126,10 +127,10 @@ def reduce(pd, *, steps: int, top: int = 10) -> dict:
         busy_ns += sum(e - s for s, e in merged)
         for n, t in self_times(ops).items():
             per_op[n] = per_op.get(n, 0) + t
-        coll = [(s, e) for n, s, e in ops + clip(lines.get(ASYNC_LINE, []))
-                if COLLECTIVE_RE.match(n)]
-        coll_ns += sum(e - s for s, e in union(coll))
         if gaps is None:
+            coll = [(s, e) for n, s, e in ops + clip(lines.get(ASYNC_LINE, []))
+                    if COLLECTIVE_RE.match(n)]
+            coll_ns = sum(e - s for s, e in union(coll))
             edges = [w0] + [x for iv in merged for x in iv] + [w1]
             gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                     if edges[i + 1] > edges[i]]
@@ -143,7 +144,7 @@ def reduce(pd, *, steps: int, top: int = 10) -> dict:
         "window_s": window_s,
         "busy_s": busy_s,
         "idle_share": 1.0 - busy_s / window_s,
-        "collective_s_per_step": coll_ns / n_dev / 1e9 / steps,
+        "collective_s_per_step": coll_ns / 1e9 / steps,
         "has_collectives": coll_ns > 0,
         "device_ops": [[n, t / n_dev / 1e9] for n, t in ops],
         "idle_gaps": [[_label((s + e) / 2, spans), (e - s) / 1e9]
