@@ -65,13 +65,13 @@ def main() -> None:
         "mse", "identical batches -> identical trajectory")
 
     # ---- ST-LLM (Fig 10 family): one index-batched train step
-    scfg = stllm.STLLMConfig(num_nodes=N, input_len=4, horizon=4, d_model=32,
-                             layers=2, n_heads=4, d_ff=64)
+    scfg = stllm.STLLMConfig(num_nodes=N, input_len=4, horizon=4, smoke=True,
+                             embed_width=16)
     sparams = stllm.init(jax.random.PRNGKey(1), scfg)
 
     def loss_stllm(p, ids):
         x, y = gather_batch(series, starts[ids], input_len=4, horizon=4)
-        return stllm.loss_fn(p, scfg, x, y), {}
+        return stllm.loss_fn(p, scfg, None, x, y), {}
 
     step = make_train_step(loss_stllm, adam, lambda s: 1e-3, donate=False)
     t = timed(lambda: step(init_train_state(sparams, adam),

@@ -1,8 +1,11 @@
 """deepseek-v2-lite-16b — MLA (kv_lora=512) + MoE 64 routed top-6 + 2 shared.
-First layer is dense (d_ff 10944); routed experts are 1408-wide.
+First layer is dense (d_ff 10944); routed experts are 1408-wide; the gate's
+top-6 softmax weights are not renormalised (``norm_topk_prob`` false,
+``routed_scaling_factor`` 1); MLA's rope is YaRN (factor 40 over 4,096
+positions, β 32/1, mscale 0.707 on both terms).
 [arXiv:2405.04434; hf-verified]"""
 from repro.configs.base import ArchSpec, full_attn_skips
-from repro.models.lm.config import LMConfig, MLAConfig, MoEConfig
+from repro.models.lm.config import LMConfig, MLAConfig, MoEConfig, RopeScaling
 
 ARCH = ArchSpec(
     id="deepseek-v2-lite-16b",
@@ -12,16 +15,21 @@ ARCH = ArchSpec(
         layers=27, d_model=2048, n_heads=16, n_kv_heads=16,
         d_ff=1408, vocab=102_400, head_dim=128,
         attn="mla", pos="rope", mlp="swiglu",
+        rope_scaling=RopeScaling(factor=40.0, original_max_position_embeddings=4096,
+                                 beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                                 mscale_all_dim=0.707),
         mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
                       qk_rope_head_dim=64, v_head_dim=128),
         moe=MoEConfig(n_experts=64, top_k=6, n_shared=2, d_expert=1408,
-                      first_k_dense=1, dense_d_ff=10_944),
+                      first_k_dense=1, dense_d_ff=10_944,
+                      norm_topk_prob=False, routed_scaling_factor=1.0),
     ),
     skips=full_attn_skips(),
     source="arXiv:2405.04434",
     smoke_overrides={
         "moe": MoEConfig(n_experts=8, top_k=2, n_shared=1, d_expert=32,
-                         first_k_dense=1, dense_d_ff=64, capacity_factor=4.0),
+                         first_k_dense=1, dense_d_ff=64, capacity_factor=4.0,
+                         norm_topk_prob=False),
         "mla": MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
                          qk_rope_head_dim=8, v_head_dim=16),
     },

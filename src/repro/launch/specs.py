@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs import get_arch
 from repro.configs.base import ArchSpec, ShapeCell
 from repro.core.batching import gather_batch_fused, lm_window_batch
-from repro.models import a3tgcn, dcrnn, pgt_dcrnn, stllm
+from repro.models import a3tgcn, dcrnn, pgt_dcrnn
 from repro.models.lm import model as lm
 from repro.optim import AdamConfig, apply_updates
 from repro.launch import sharding as shd
@@ -223,13 +223,13 @@ def build_lm_prefill(arch: ArchSpec, cell: ShapeCell, mesh: Mesh, *,
     hints = act_hints(cfg, mesh)
     if moe_groups > 1:
         dp = dp_axes(mesh)
-        hints = {**hints, "moe_groups": moe_groups,
-                 "moe_group": NamedSharding(mesh, P(dp, None, None)),
+        hints = {**hints, "moe_group": NamedSharding(mesh, P(dp, None, None)),
                  "moe_disp": NamedSharding(mesh, P(dp, None, None, None))}
 
     def step(params, tokens, cache):
         logits, new_cache, lengths = lm.prefill(params, cfg, tokens, cache,
-                                                shardings=hints)
+                                                shardings=hints,
+                                                moe_groups=moe_groups)
         return logits, new_cache, lengths
 
     return CellProgram(

@@ -19,19 +19,21 @@ def _grouped(q, n_kv):
 
 
 def full_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                   q_offset=0, kv_valid_from=0):
+                   q_offset=0, kv_valid_from=0, scale: float | None = None):
     """q: [B, Sq, H, D], k/v: [B, Skv, Hkv, D] -> [B, Sq, H, D].
 
     ``q_offset``: position of q[0] relative to k[0] (decode / banded chunks).
     ``kv_valid_from``: keys below this index are masked (padding).
+    ``scale``: the softmax scale (default ``1/sqrt(D)``).
     Materialises the [Sq, Skv] score matrix — use :func:`blockwise_attention`
     for long sequences.
     """
     b, sq, h, d = q.shape
     n_kv = k.shape[2]
     qg = _grouped(q, n_kv)
+    scale = 1.0 / jnp.sqrt(jnp.float32(d)) if scale is None else scale
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
+                        k.astype(jnp.float32)) * scale
     qpos = q_offset + jnp.arange(sq)[:, None]
     kpos = jnp.arange(k.shape[1])[None, :]
     mask = kpos >= kv_valid_from
@@ -46,7 +48,8 @@ def full_attention(q, k, v, *, causal: bool = True, window: int | None = None,
 
 
 def blockwise_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                        q_chunk: int = 512, kv_chunk: int = 512):
+                        q_chunk: int = 512, kv_chunk: int = 512,
+                        scale: float | None = None):
     """Flash-style online-softmax attention in pure JAX.
 
     Structure matters for BOTH directions of autodiff:
@@ -60,17 +63,21 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int | None = No
     masking-free early bounds (the mask zeroes them; XLA DCEs full-block
     no-ops only with static bounds, so we keep the scan dense — acceptable:
     2× the minimal FLOPs on the strictly-lower triangle).
+    Sequences that do not divide the chunks are padded at the end; padded
+    keys are masked and padded queries dropped.
     """
-    b, s, h, d = q.shape
+    b, s_in, h, d = q.shape
     n_kv = k.shape[2]
-    skv = k.shape[1]
-    assert s % q_chunk == 0 and skv % kv_chunk == 0, (s, q_chunk, skv, kv_chunk)
+    skv_in = k.shape[1]
+    s, skv = -(-s_in // q_chunk) * q_chunk, -(-skv_in // kv_chunk) * kv_chunk
+    pad = lambda a, n: jnp.pad(a, ((0, 0), (0, n - a.shape[1]), (0, 0), (0, 0)))
+    q, k, v = pad(q, s), pad(k, skv), pad(v, skv)
     nq, nk = s // q_chunk, skv // kv_chunk
     g = h // n_kv
 
     kc_all = k.reshape(b, nk, kv_chunk, n_kv, d)
     vc_all = v.reshape(b, nk, kv_chunk, n_kv, d)
-    scale = 1.0 / jnp.sqrt(jnp.float32(d))
+    scale = 1.0 / jnp.sqrt(jnp.float32(d)) if scale is None else scale
 
     @jax.checkpoint
     def one_q_chunk(qi):
@@ -84,7 +91,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int | None = No
             s_blk = jnp.einsum("bqhgd,bkhd->bqhgk", qg.astype(jnp.float32),
                                k_blk.astype(jnp.float32)) * scale
             kpos = ki * kv_chunk + jnp.arange(kv_chunk)[None, :]
-            mask = jnp.ones((q_chunk, kv_chunk), bool)
+            mask = jnp.broadcast_to(kpos < skv_in, (q_chunk, kv_chunk))
             if causal:
                 mask &= kpos <= qpos
             if window is not None:
@@ -110,7 +117,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, window: int | None = No
         return out.reshape(b, q_chunk, h, d).astype(q.dtype)
 
     out = jax.lax.map(one_q_chunk, jnp.arange(nq))  # [nq, b, qc, h, d]
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)[:, :s_in]
 
 
 def banded_attention(q, k, v, *, window: int, q_chunk: int = 512):

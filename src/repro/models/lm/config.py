@@ -22,6 +22,20 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_noise: float = 0.0
     aux_loss_coef: float = 0.01
+    # DeepSeek-V2's gate: renormalise the top-k weights to sum 1, or else
+    # scale them by ``routed_scaling_factor``
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the experts this layer holds: ``experts_held`` of ``n_experts`` from
+    # ``first_expert`` on (None: all).  The router keeps all ``n_experts``
+    # outputs; the layer computes its held experts' part of the result
+    # (expert parallelism without the exchange).
+    experts_held: int | None = None
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,6 +44,17 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (Peng et al. 2023) as DeepSeek-V2's ``rope_scaling`` gives it."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +72,7 @@ class LMConfig:
     window: int | None = None  # swa / recurrentgemma local-attn window
     pos: Literal["rope", "learned", "none"] = "rope"
     rope_theta: float = 10_000.0
+    rope_scaling: RopeScaling | None = None  # MLA's rope (deepseek)
     max_seq_len: int = 8192  # learned-pos table size / cache default
     mlp: Literal["swiglu", "geglu", "gelu", "relu_sq"] = "swiglu"
     moe: MoEConfig | None = None

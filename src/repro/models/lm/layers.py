@@ -1,6 +1,8 @@
 """Shared LM layers: norms, embeddings, RoPE, MLP variants."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -26,17 +28,55 @@ def linear(p, x):
 
 
 # ----------------------------------------------------------------------- RoPE
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def _yarn_dim(rotations: float, head_dim: int, theta: float, original: int) -> float:
+    """The rotary dimension that turns ``rotations`` times over ``original``
+    positions."""
+    return (head_dim * math.log(original / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies ``[D/2]``.  With YaRN ``scaling`` (a
+    ``RopeScaling``), frequencies that turn fewer than ``beta_slow`` times
+    over the original context are divided by ``factor``, those that turn more
+    than ``beta_fast`` times are kept, and a linear ramp joins them."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    orig = scaling.original_max_position_embeddings
+    low = max(math.floor(_yarn_dim(scaling.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_dim(scaling.beta_slow, head_dim, theta, orig)),
+               head_dim - 1)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_softmax_factor(scaling) -> float:
+    """YaRN's factor on the softmax scale: ``mscale(mscale_all_dim)**2``."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
     """x: [B, S, H, D], positions: [B, S] (absolute token positions)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [D/2]
+    freqs = rope_freqs(d, theta, scaling)  # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaling is not None:
+        amp = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

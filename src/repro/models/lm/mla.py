@@ -10,15 +10,21 @@ are taken against the cached ``c_kv`` directly, and the value projection W_uv
 is applied to the attended latent — so the per-token cache cost is
 ``kv_lora_rank + rope_dim`` (576) instead of ``2·H·D`` (4096 for 16 heads):
 the paper-relevant memory saving that makes decode_32k × batch 128 fit.
+
+With ``cfg.rope_scaling`` (YaRN, as DeepSeek-V2 publishes it) the rope parts
+use YaRN's inverse frequencies and the softmax scale ``1/sqrt(dn + dr)`` is
+multiplied by ``mscale(mscale_all_dim)**2``, in every path alike.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro import trace_names
 from repro.models.lm.attention import NEG_INF, blockwise_attention, full_attention
 from repro.models.lm.config import LMConfig
-from repro.models.lm.layers import apply_rope, init_linear, linear, rms_norm
+from repro.models.lm.layers import (apply_rope, init_linear, linear, rms_norm,
+                                    yarn_softmax_factor)
 
 
 def init_mla(rng, cfg: LMConfig, dtype=jnp.float32):
@@ -39,7 +45,7 @@ def _project_q(p, cfg: LMConfig, x, positions):
     b, s, _ = x.shape
     q = linear(p["wq"], x).reshape(b, s, cfg.n_heads, m.qk_nope_head_dim + m.qk_rope_head_dim)
     qn, qr = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg.rope_theta, cfg.rope_scaling)
     return qn, qr
 
 
@@ -48,10 +54,18 @@ def _project_ckv(p, cfg: LMConfig, x, positions):
     ckv_full = linear(p["wdkv"], x)
     c_kv, k_pe = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm(c_kv, p["ckv_norm"].astype(x.dtype), cfg.norm_eps)
-    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
     return c_kv, k_pe  # [B,S,r], [B,S,dr]
 
 
+def softmax_scale(cfg: LMConfig) -> float:
+    m = cfg.mla
+    return ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+            * yarn_softmax_factor(cfg.rope_scaling))
+
+
+@jax.named_scope(trace_names.ATTN_MLA)
 def mla_attention(p, cfg: LMConfig, x, positions, *, blockwise: bool = False):
     """Train/prefill path (decompressed).  x: [B, S, d] -> ([B, S, d], (c_kv, k_pe))."""
     m = cfg.mla
@@ -66,11 +80,12 @@ def mla_attention(p, cfg: LMConfig, x, positions, *, blockwise: bool = False):
     dq = q.shape[-1]
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dq - m.v_head_dim)))
     fn = blockwise_attention if blockwise else full_attention
-    out = fn(q, k, vp, causal=True)[..., : m.v_head_dim]
+    out = fn(q, k, vp, causal=True, scale=softmax_scale(cfg))[..., : m.v_head_dim]
     y = linear(p["wo"], out.reshape(b, s, -1))
     return y, (c_kv, k_pe)
 
 
+@jax.named_scope(trace_names.ATTN_MLA)
 def mla_decode(p, cfg: LMConfig, x1, ckv_cache, kpe_cache, lengths, *, paged=None):
     """Absorbed one-token decode.  x1: [B, 1, d]; caches: [B, S_max, r]/[B, S_max, dr].
 
@@ -106,7 +121,7 @@ def mla_decode(p, cfg: LMConfig, x1, ckv_cache, kpe_cache, lengths, *, paged=Non
     w_uk = wukv[..., : m.qk_nope_head_dim]  # [r, H, dn]
     w_uv = wukv[..., m.qk_nope_head_dim :]  # [r, H, dv]
     q_lat = jnp.einsum("bqhd,rhd->bqhr", qn, w_uk.astype(x1.dtype))  # [B,1,H,r]
-    scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bqhr,bkr->bhqk", q_lat.astype(jnp.float32), ckv.astype(jnp.float32))
               + jnp.einsum("bqhd,bkd->bhqk", qr.astype(jnp.float32), kpe.astype(jnp.float32))
               ) * scale

@@ -19,6 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import trace_names
 from repro.models.lm import rglru, rwkv6
 from repro.models.lm.attention import (
     NEG_INF,
@@ -125,19 +126,22 @@ def _init_layer(rng, cfg: LMConfig, spec: LayerSpec, dtype):
     return p
 
 
-def init(rng, cfg: LMConfig):
+def init(rng, cfg: LMConfig, *, tokens: bool = True):
+    """The model's parameters; ``tokens=False`` leaves out the token table,
+    the learned positions and the LM head, for a model that feeds the blocks
+    its own embeddings and reads their hidden states (``backbone``)."""
     dtype = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(rng, len(stage_plan(cfg)) + 3)
-    params: dict[str, Any] = {
-        "embed": (jax.random.normal(ks[0], (cfg.padded_vocab, cfg.d_model), jnp.float32)
-                  * 0.02).astype(dtype),
-        "final_norm": jnp.ones((cfg.d_model,), dtype),
-    }
-    if cfg.pos == "learned":
-        params["pos"] = (jax.random.normal(ks[1], (cfg.max_seq_len, cfg.d_model),
-                                           jnp.float32) * 0.02).astype(dtype)
-    if not cfg.tie_embeddings:
-        params["lm_head"] = init_linear(ks[2], cfg.d_model, cfg.padded_vocab, dtype=dtype)
+    params: dict[str, Any] = {"final_norm": jnp.ones((cfg.d_model,), dtype)}
+    if tokens:
+        params["embed"] = (jax.random.normal(
+            ks[0], (cfg.padded_vocab, cfg.d_model), jnp.float32) * 0.02).astype(dtype)
+        if cfg.pos == "learned":
+            params["pos"] = (jax.random.normal(ks[1], (cfg.max_seq_len, cfg.d_model),
+                                               jnp.float32) * 0.02).astype(dtype)
+        if not cfg.tie_embeddings:
+            params["lm_head"] = init_linear(ks[2], cfg.d_model, cfg.padded_vocab,
+                                            dtype=dtype)
     stages = []
     for si, (specs, repeats) in enumerate(stage_plan(cfg)):
         layer_keys = jax.random.split(ks[3 + si], repeats)
@@ -277,9 +281,11 @@ def _ring_decode(q1, k_ring, v_ring, n_valid):
 
 # --------------------------------------------------------------------- layers
 def _layer_apply(p, cfg: LMConfig, spec: LayerSpec, x, positions, *, mode,
-                 cache=None, lengths=None, shardings=None, paged=None):
-    """One block.  Returns (x, new_cache, aux_loss)."""
+                 cache=None, lengths=None, shardings=None, paged=None,
+                 moe_groups: int = 1):
+    """One block.  Returns (x, new_cache, aux_loss, dropped MoE slots)."""
     aux = jnp.zeros((), jnp.float32)
+    dropped = jnp.zeros((), jnp.int32)
     h = rms_norm(x, p["norm1"].astype(x.dtype), cfg.norm_eps)
 
     if spec.mixer == "rec":
@@ -300,53 +306,57 @@ def _layer_apply(p, cfg: LMConfig, spec: LayerSpec, x, positions, *, mode,
             p["rwkv"], cfg, h2, cache=None if mode == "train" else cache and cache["cm"])
         x = x + out2
         new_cache = None if mode == "train" else {"tm": new_mix_cache, "cm": new_cm_cache}
-        return x, new_cache, aux
+        return x, new_cache, aux, dropped
 
     h2 = rms_norm(x, p["norm2"].astype(x.dtype), cfg.norm_eps)
     if spec.ffn == "moe":
-        groups = (shardings or {}).get("moe_groups", 1)
-        out2, aux = moe_ffn(p["moe"], h2, cfg.moe, cfg.mlp, shardings=shardings,
-                            groups=groups)
+        out2, aux, dropped = moe_ffn(p["moe"], h2, cfg.moe, cfg.mlp,
+                                     shardings=shardings, groups=moe_groups)
     else:
-        out2 = mlp(p["mlp"], h2, cfg.mlp)
+        with jax.named_scope(trace_names.FFN_DENSE):
+            out2 = mlp(p["mlp"], h2, cfg.mlp)
     x = x + out2
-    return x, new_mix_cache, aux
+    return x, new_mix_cache, aux, dropped
 
 
 def _run_stages(params, cfg: LMConfig, x, positions, *, mode, caches=None,
-                lengths=None, remat=False, shardings=None, paged=None):
-    """Scan over each stage's repeats.  Returns (x, new_caches, aux_total)."""
+                lengths=None, remat=False, shardings=None, paged=None,
+                moe_groups: int = 1):
+    """Scan over each stage's repeats.  Returns (x, new_caches, aux_total,
+    dropped): ``dropped`` counts the (token, expert) pairs that MoE layers'
+    capacity turned away; ``moe_groups`` is their number of dispatch groups."""
     plan = stage_plan(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    totals = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
     new_caches = []
     for (specs, repeats), stage_p, stage_c in zip(
             plan, params["stages"], caches or [None] * len(plan)):
 
         def body(carry, layer_in):
-            xx, aux_acc = carry
+            xx, (aux_acc, drop_acc) = carry
             lp, lc = layer_in
             out_caches = {}
             for i, sp in enumerate(specs):
                 sub_c = None if lc is None else lc[f"sub{i}"]
-                xx, nc, aux = _layer_apply(lp[f"sub{i}"], cfg, sp, xx, positions,
-                                           mode=mode, cache=sub_c, lengths=lengths,
-                                           shardings=shardings, paged=paged)
+                xx, nc, aux, dropped = _layer_apply(
+                    lp[f"sub{i}"], cfg, sp, xx, positions, mode=mode, cache=sub_c,
+                    lengths=lengths, shardings=shardings, paged=paged,
+                    moe_groups=moe_groups)
                 xx = _constrain(xx, shardings, "act")
                 out_caches[f"sub{i}"] = nc
-                aux_acc = aux_acc + aux
-            return (xx, aux_acc), out_caches
+                aux_acc, drop_acc = aux_acc + aux, drop_acc + dropped
+            return (xx, (aux_acc, drop_acc)), out_caches
 
         if remat:
             body = jax.checkpoint(body)
         if stage_c is None:
-            (x, aux_total), scanned = jax.lax.scan(
-                lambda c, lp: body(c, (lp, None)), (x, aux_total), stage_p)
+            (x, totals), scanned = jax.lax.scan(
+                lambda c, lp: body(c, (lp, None)), (x, totals), stage_p)
             new_caches.append(scanned if mode != "train" else None)
         else:
-            (x, aux_total), scanned = jax.lax.scan(
-                body, (x, aux_total), (stage_p, stage_c))
+            (x, totals), scanned = jax.lax.scan(
+                body, (x, totals), (stage_p, stage_c))
             new_caches.append(scanned)
-    return x, new_caches, aux_total
+    return (x, new_caches) + totals
 
 
 # ----------------------------------------------------------------- public API
@@ -382,22 +392,26 @@ def forward(params, cfg: LMConfig, tokens, *, prefix_embeds=None, remat=False,
     """Training forward.  Returns (logits [B, S, V], aux_loss)."""
     x, positions = embed_tokens(params, cfg, tokens, prefix_embeds=prefix_embeds)
     x = _constrain(x, shardings, "act")
-    x, _, aux = _run_stages(params, cfg, x, positions, mode="train", remat=remat,
-                            shardings=shardings)
+    x, _, aux, _ = _run_stages(params, cfg, x, positions, mode="train",
+                               remat=remat, shardings=shardings)
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     logits = _constrain(logits_fn(params, cfg, x), shardings, "logits")
     return logits, aux
 
 
-def backbone(params, cfg: LMConfig, x_embeds, *, remat=False, shardings=None):
+def backbone(params, cfg: LMConfig, x_embeds, *, remat=False, shardings=None,
+             moe_groups: int = 1):
     """Run the block stack on precomputed embeddings (ST-LLM / modality
-    frontends).  x_embeds: [B, S, d] -> (hidden [B, S, d], aux)."""
+    frontends), causal over the sequence, positions 0..S-1.
+    x_embeds: [B, S, d] -> (final-normed hidden [B, S, d], aux, dropped)."""
     b, s, _ = x_embeds.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     x = _constrain(x_embeds.astype(jnp.dtype(cfg.dtype)), shardings, "act")
-    x, _, aux = _run_stages(params, cfg, x, positions, mode="train", remat=remat,
-                            shardings=shardings)
-    return rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps), aux
+    x, _, aux, dropped = _run_stages(params, cfg, x, positions, mode="train",
+                                     remat=remat, shardings=shardings,
+                                     moe_groups=moe_groups)
+    x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
+    return x, aux, dropped
 
 
 def loss_fn(params, cfg: LMConfig, tokens_in, labels, *, prefix_embeds=None,
@@ -576,12 +590,13 @@ def scatter_cache_paged(cache, sub, slots, phys, *, block_size: int, mask):
 
 
 def prefill(params, cfg: LMConfig, tokens, cache, *, prefix_embeds=None,
-            shardings=None):
+            shardings=None, moe_groups: int = 1):
     """Fill the cache from a prompt.  Returns (last-token logits, cache, lengths)."""
     x, positions = embed_tokens(params, cfg, tokens, prefix_embeds=prefix_embeds)
     x = _constrain(x, shardings, "act")
-    x, new_caches, _ = _run_stages(params, cfg, x, positions, mode="prefill",
-                                   caches=cache, shardings=shardings)
+    x, new_caches, _, _ = _run_stages(params, cfg, x, positions, mode="prefill",
+                                      caches=cache, shardings=shardings,
+                                      moe_groups=moe_groups)
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     logits = logits_fn(params, cfg, x[:, -1:])[:, 0]
     lengths = jnp.full((tokens.shape[0],), x.shape[1], jnp.int32)
@@ -599,8 +614,8 @@ def decode_step(params, cfg: LMConfig, token, cache, lengths, *, shardings=None,
     """
     x, positions = embed_tokens(params, cfg, token, pos_offset=lengths)
     x = _constrain(x, shardings, "act")
-    x, new_caches, _ = _run_stages(params, cfg, x, positions, mode="decode",
-                                   caches=cache, lengths=lengths,
-                                   shardings=shardings, paged=paged)
+    x, new_caches, _, _ = _run_stages(params, cfg, x, positions, mode="decode",
+                                      caches=cache, lengths=lengths,
+                                      shardings=shardings, paged=paged)
     x = rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     return logits_fn(params, cfg, x[:, 0]), new_caches

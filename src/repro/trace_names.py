@@ -17,6 +17,19 @@ OPTIMIZER = "optimizer"  # the schedule, clipping and Adam
 
 DEVICE_SCOPES = (GATHER, RNN_SCAN, DCGRU, DCONV_HOP, OPTIMIZER)
 
+# Device scopes of the LM blocks (ST-LLM's backbone).  The benchmark's layer
+# split reads DEVICE_SCOPES alone; it takes these in with the per-scope
+# metrics of PERF.md's Open questions 1.
+ATTN_MLA = "attn.mla"          # MLA: projections, rope, attention, output
+MOE_ROUTE = "moe.route"        # router logits, softmax, top-k weights
+MOE_DISPATCH = "moe.dispatch"  # sort, capacity positions, gather to [E, C, d]
+MOE_EXPERTS = "moe.experts"    # the held and the shared experts' FFNs
+MOE_COMBINE = "moe.combine"    # weighted scatter-add back to the tokens
+FFN_DENSE = "ffn.dense"        # a dense layer's FFN
+
+LM_SCOPES = (ATTN_MLA, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
+             FFN_DENSE)
+
 # Host spans.
 FIT_START = "fit.start"          # Engine.fit's entry to the first step
 FIT_END = "fit.end"              # the last step to Engine.fit's return

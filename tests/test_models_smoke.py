@@ -152,11 +152,13 @@ def test_pgt_dcrnn_and_a3tgcn_and_stllm():
     pred = a3tgcn.apply(a3tgcn.init(KEY, acfg), acfg, a_hat, x)
     assert pred.shape == (2, 4, n, 1)
 
-    scfg = stllm.STLLMConfig(num_nodes=n, input_len=4, horizon=4, d_model=32,
-                             layers=2, n_heads=4, d_ff=64)
-    pred = stllm.apply(stllm.init(KEY, scfg), scfg, x)
+    scfg = stllm.STLLMConfig(num_nodes=n, input_len=4, horizon=4, smoke=True,
+                             embed_width=16)
+    sparams = stllm.init(KEY, scfg)
+    pred = stllm.apply(sparams, scfg, x)
     assert pred.shape == (2, 4, n, 1)
     assert not bool(jnp.any(jnp.isnan(pred)))
+    assert np.isfinite(float(stllm.loss_fn(sparams, scfg, sup, x, y)))
 
 
 def test_param_counts_match_billing():

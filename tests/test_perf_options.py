@@ -40,9 +40,9 @@ def test_grouped_moe_matches_global_exact():
     moe = MoEConfig(n_experts=8, top_k=2, capacity_factor=8.0, n_shared=2)
     p = init_moe(KEY, 64, moe, 128, "swiglu")
     x = jax.random.normal(jax.random.PRNGKey(3), (4, 32, 64))
-    y1, a1 = moe_ffn(p, x, moe, "swiglu")
+    y1, a1, _ = moe_ffn(p, x, moe, "swiglu")
     for g in (2, 4, 8):
-        y2, a2 = moe_ffn(p, x, moe, "swiglu", groups=g)
+        y2, a2, _ = moe_ffn(p, x, moe, "swiglu", groups=g)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-5,
                                    err_msg=f"groups={g}")
         assert abs(float(a1) - float(a2)) < 1e-6
@@ -54,7 +54,7 @@ def test_grouped_moe_grad_flows():
     x = jax.random.normal(KEY, (2, 16, 32))
 
     def loss(p):
-        y, aux = moe_ffn(p, x, moe, "swiglu", groups=4)
+        y, aux, _ = moe_ffn(p, x, moe, "swiglu", groups=4)
         return jnp.sum(y ** 2) + aux
 
     g = jax.grad(loss)(p)
